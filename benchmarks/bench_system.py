@@ -18,7 +18,11 @@ def bench_kernels():
     from repro.kernels.flash_attention import ref as fa_ref
     from repro.kernels.ssd_scan import ops as ssd_ops, ref as ssd_ref
     from repro.kernels.cachesim_step import ops as sim_ops
+    from repro.kernels._compat import interpret_mode
 
+    dev = jax.devices()[0]
+    device = (f"device={dev.platform}:{dev.device_kind};"
+              f"interpret={interpret_mode()}")
     ks = jax.random.split(jax.random.PRNGKey(0), 6)
     B, S, H, D = 1, 512, 4, 64
     q = jax.random.normal(ks[0], (B, S, H, D), jnp.float32)
@@ -29,8 +33,8 @@ def bench_kernels():
         for _ in range(3):
             fa_ops.flash_attention(q, k, v).block_until_ready()
     flops = 4 * B * S * S * H * D * 0.5
-    emit("kernel.flash_attention_interp", t["us"] / 3,
-         f"S={S};flops={flops:.2e};note=interpret_mode_cpu")
+    emit("kernel.flash_attention", t["us"] / 3,
+         f"S={S};flops={flops:.2e};{device}")
 
     b, S2, h, p, n = 1, 512, 8, 64, 64
     x = jax.random.normal(ks[3], (b, S2, h, p))
@@ -44,7 +48,7 @@ def bench_kernels():
         for _ in range(3):
             ssd_ops.ssd_scan(x, dt, A, Bm, Cm, Dm,
                              chunk=128)[0].block_until_ready()
-    emit("kernel.ssd_scan_interp", t["us"] / 3, f"S={S2};chunk=128")
+    emit("kernel.ssd_scan", t["us"] / 3, f"S={S2};chunk=128;{device}")
 
     rows, ways, T = 512, 8, 64
     tags = jnp.full((rows, ways), -1, jnp.int32)
@@ -56,7 +60,7 @@ def bench_kernels():
         sim_ops.simulate_rows(tags, age, streams)[0].block_until_ready()
     emit("kernel.cachesim_rows", t["us"],
          f"rows={rows};T={T};accesses={rows*T};"
-         f"per_access_ns={t['us']*1e3/(rows*T):.0f}")
+         f"per_access_ns={t['us']*1e3/(rows*T):.0f};{device}")
 
 
 def bench_train_step():

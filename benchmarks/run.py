@@ -3,7 +3,9 @@
     PYTHONPATH=src python -m benchmarks.run            # everything
     PYTHONPATH=src python -m benchmarks.run --only fig10,roofline
 
-Prints ``name,us_per_call,derived`` CSV rows (harness contract).
+Prints ``name,us_per_call,derived`` CSV rows (harness contract).  A
+section that raises prints a ``<section>.ERROR`` row, the others still
+run, and the process exits 1.
 
 Trajectory artifacts: when ``$BENCH_PR`` is set (e.g. ``BENCH_PR=5``),
 headline metrics each section `record()`s are flushed to
@@ -87,6 +89,8 @@ def main() -> None:
     args = ap.parse_args()
     filters = [f for f in args.only.split(",") if f]
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import bench_paper_tables, bench_system
 
     sections = [
@@ -116,6 +120,7 @@ def main() -> None:
     print("name,us_per_call,derived")
     t0 = time.time()
     ran = []
+    failed = []
     for name, fn in sections:
         if filters and not any(f in name for f in filters):
             continue
@@ -123,6 +128,7 @@ def main() -> None:
         try:
             fn()
         except Exception as e:  # keep the harness going; failures are rows
+            failed.append(name)
             print(f"{name}.ERROR,0.0,{type(e).__name__}: {e}",
                   file=sys.stdout, flush=True)
     wall = time.time() - t0
@@ -130,6 +136,8 @@ def main() -> None:
     if pr and not args.no_trajectory:
         flush_trajectory(pr, ran, wall)
     print(f"# total_wall_s,{wall:.1f},", flush=True)
+    if failed:
+        sys.exit(f"benchmark sections failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -1296,10 +1296,11 @@ class ShardedFleet:
         ``ScaleSpec.max_guests_per_dispatch`` capping per-dispatch
         padding memory.  Results stay bit-identical at any shard size.
       * **Device mapping** — `~repro.core.fleetshard.device_groups`
-        deals contiguous shard runs to local devices; each group runs
-        its lockstep cohort under ``jax.default_device``.  Single-device
-        hosts (CI) degenerate to the batched-vmap fallback: one group,
-        shards back-to-back.
+        deals contiguous shard runs to local devices; each group's
+        machine states move to its device and its lockstep cohort runs
+        under ``jax.default_device``.  Single-device hosts (CI)
+        degenerate to the batched-vmap fallback: one group, shards
+        back-to-back.
 
     Guest loop sizing defaults to the platform's
     :class:`~repro.core.platforms.ScaleSpec` profile (fewer, shorter
@@ -1360,7 +1361,11 @@ class ShardedFleet:
             for sim in self.sims:
                 sim.install_lowering(choice.lowering)
             for dev, sl in groups:
-                with on_device(dev):
+                with on_device(dev) as device:
+                    if device is not None:    # the group's state lives here
+                        for sim in self.sims[sl]:
+                            sim.host.state = jax.device_put(
+                                sim.host.state, device)
                     reports.extend(_run_lockstep(self.sims[sl]))
         run_s = time.perf_counter() - t0
         wall = self.boot_s + run_s

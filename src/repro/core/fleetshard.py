@@ -44,6 +44,8 @@ import dataclasses
 import math
 from typing import Dict, List, Optional, Tuple
 
+import jax
+
 from repro.core.host_model import shard_slices
 from repro.core.plancost import (COMPILE_S, DISPATCH_OVERHEAD_S, HORIZON,
                                  SHAPE_CACHE, STEP_COST_S, SWITCH_MARGIN,
@@ -174,13 +176,9 @@ def choose_shard(platform, plan: Optional[ProbePlan] = None,
 # ---------------------------------------------------------------------------
 
 def local_device_count() -> int:
-    """Accelerator devices visible to this process (1 on the CPU
-    containers CI runs on)."""
-    try:
-        import jax
-        return max(1, len(jax.local_devices()))
-    except Exception:          # pragma: no cover - jax always importable here
-        return 1
+    """Devices visible to this process (1 on the CPU containers CI runs
+    on)."""
+    return len(jax.local_devices())
 
 
 def device_groups(n_guests: int,
@@ -211,18 +209,16 @@ def device_groups(n_guests: int,
 
 @contextlib.contextmanager
 def on_device(index: int):
-    """Run the body's dispatches on local device ``index`` (no-op when
-    only one device is visible)."""
-    try:
-        import jax
-        devs = jax.local_devices()
-    except Exception:          # pragma: no cover
-        devs = []
+    """Run the body's dispatches on local device ``index``; yields that
+    device, or None (and changes nothing) when only one device is
+    visible."""
+    devs = jax.local_devices()
     if len(devs) <= 1:
-        yield
+        yield None
         return
-    with jax.default_device(devs[index % len(devs)]):
-        yield
+    dev = devs[index % len(devs)]
+    with jax.default_device(dev):
+        yield dev
 
 
 # ---------------------------------------------------------------------------
@@ -230,8 +226,10 @@ def on_device(index: int):
 # ---------------------------------------------------------------------------
 
 class StreamingMean:
-    """Exact running-sum mean: ``value() == sum(samples) / len(samples)``
-    bit for bit, because it *is* that computation performed online."""
+    """Exact running-sum mean: a plain left fold of the samples divided by
+    their count, bit for bit, because it *is* that computation performed
+    online (Python >= 3.12's built-in ``sum`` of floats is compensated and
+    may differ in the last bits)."""
 
     __slots__ = ("_sum", "n")
 

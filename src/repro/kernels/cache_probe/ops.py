@@ -9,16 +9,13 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.cache_probe.kernel import prime_probe, triad
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels._compat import interpret_mode, lane_block
+from repro.kernels.cache_probe.kernel import TRIAD_BLOCK, prime_probe, triad
 
 
 @jax.jit
 def probe_triad(a, b, scale):
-    return triad(a, b, scale, interpret=not _on_tpu())
+    return triad(a, b, scale, interpret=interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("clock0",))
@@ -30,14 +27,17 @@ def probe_verdicts(tags, age, streams, targets, clock0: int = 1):
     against the full machine simulator's batched engine (which adds slices,
     the L2 layer and back-invalidation) in tests/test_platforms.py.
     """
-    lanes = tags.shape[0]
-    block = lanes
-    for b in (256, 128, 64, 32, 16, 8, 4, 2, 1):
-        if lanes % b == 0 and b <= lanes:
-            block = b
-            break
-    return prime_probe(tags, age, streams, targets, block_lanes=block,
-                       clock0=clock0, interpret=not _on_tpu())
+    return prime_probe(tags, age, streams, targets,
+                       block_lanes=lane_block(tags.shape[0]),
+                       clock0=clock0, interpret=interpret_mode())
+
+
+def triad_rows(n_bytes: int) -> int:
+    """Rows of the (rows, 128) f32 triad operands for an ``n_bytes``
+    working set over three streams: a whole number of triad blocks, at
+    least one."""
+    n_elems = n_bytes // 4 // 3
+    return max(1, n_elems // 128 // TRIAD_BLOCK) * TRIAD_BLOCK
 
 
 def measure_hbm_bandwidth(n_bytes: int = 256 * (1 << 20),
@@ -46,8 +46,7 @@ def measure_hbm_bandwidth(n_bytes: int = 256 * (1 << 20),
     (effective_bytes_per_s, elapsed_s).  On real TPU this is the paper's
     eviction-rate analogue; on CPU it validates the code path (the
     simulated-contention clock in tpuprobe.monitor feeds the policy)."""
-    n_elems = n_bytes // 4 // 3          # three f32 streams
-    rows = max(8, (n_elems // 128) // 8 * 8)
+    rows = triad_rows(n_bytes)
     a = jnp.ones((rows, 128), jnp.float32)
     b = jnp.ones((rows, 128), jnp.float32)
     s = jnp.ones((1,), jnp.float32)

@@ -6,20 +6,11 @@ import functools
 
 import jax
 
+from repro.kernels._compat import interpret_mode, lane_block
 from repro.kernels.cachesim_step.kernel import lru_sets
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("clock0",))
 def simulate_rows(tags, age, streams, clock0: int = 1):
-    rows = tags.shape[0]
-    block = rows
-    for b in (256, 128, 64, 32, 16, 8, 4, 2, 1):
-        if rows % b == 0 and b <= rows:
-            block = b
-            break
-    return lru_sets(tags, age, streams, block_rows=block, clock0=clock0,
-                    interpret=not _on_tpu())
+    return lru_sets(tags, age, streams, block_rows=lane_block(tags.shape[0]),
+                    clock0=clock0, interpret=interpret_mode())

@@ -2,8 +2,8 @@
 
 `flash_attention(q, k, v)` takes the model's (B, S, H, D) layout, picks
 block sizes from the probed-VMEM budget (CAP-TPU tile selection), and runs
-the Pallas kernel — in interpret mode automatically off-TPU so the same
-call validates everywhere.
+the Pallas kernel — compiled on a TPU, interpreted on the CPU backend
+(`repro.kernels._compat.interpret_mode`).
 """
 
 from __future__ import annotations
@@ -13,11 +13,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels._compat import interpret_mode
 from repro.kernels.flash_attention.kernel import flash_attention_bhsd
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
@@ -29,5 +26,5 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
     vt = v.transpose(0, 2, 1, 3)
     out = flash_attention_bhsd(qt, kt, vt, causal=causal,
                                block_q=block_q, block_k=block_k,
-                               interpret=not _on_tpu())
+                               interpret=interpret_mode())
     return out.transpose(0, 2, 1, 3)

@@ -13,11 +13,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels._compat import interpret_mode
 from repro.kernels.ssd_scan.kernel import ssd_scan_grid
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "block_h"))
@@ -46,7 +43,7 @@ def ssd_scan(x, dt, A, B, C, D, chunk: int = 128, initial_state=None,
     Ck = C.astype(jnp.float32).reshape(b, nc, L, n)
 
     y, st = ssd_scan_grid(xk.astype(jnp.float32), dtk, dAk, Bk, Ck,
-                          block_h=block_h, interpret=not _on_tpu())
+                          block_h=block_h, interpret=interpret_mode())
     y = y.transpose(0, 2, 3, 1, 4).reshape(b, S, h, p)
     y = y + x.astype(jnp.float32) * D.astype(jnp.float32)[None, None, :, None]
     return y.astype(x.dtype), st

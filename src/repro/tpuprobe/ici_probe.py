@@ -20,7 +20,6 @@ from typing import Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.launch.mesh import ICI_BW_PER_LINK
@@ -29,7 +28,7 @@ from repro.launch.mesh import ICI_BW_PER_LINK
 def _axis_psum_probe(mesh: Mesh, axis: str, n_floats: int = 1 << 16):
     """A jitted one-axis psum over a small buffer."""
 
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=P(axis), out_specs=P(axis))
     def probe(x):
         return jax.lax.psum(x, axis) / mesh.shape[axis]
@@ -43,7 +42,7 @@ def _ring_permute_probe(mesh: Mesh, axis: str, n_floats: int = 1 << 16):
     size = mesh.shape[axis]
     perm = [(i, (i + 1) % size) for i in range(size)]
 
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=P(axis), out_specs=P(axis))
     def probe(x):
         return jax.lax.ppermute(x, axis, perm)

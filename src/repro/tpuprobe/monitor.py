@@ -13,10 +13,12 @@ qualitative tiers with 3-interval hysteresis feeding CAS-TPU
 (`distributed/rebalance.py`) and CAP-TPU (`vmem_probe.pick_*` +
 `data/pipeline.ColoredStagingPool`).
 
-Clock injection: on real TPUs `clock=None` times the actual kernels; this
-CPU container has no TPU, so tests/examples inject a `SimClock` whose
-contention schedule plays back interference — the full control path
-(probe -> EWMA -> tier -> rebalance) is exercised identically.
+Clock injection: on real TPUs `clock=None` times the actual kernels on
+each local device and normalizes by that chip's published HBM peak
+(`launch.mesh.hbm_peak_bytes_per_s`, keyed by `device_kind`); CPU tests
+and examples inject a `SimClock` whose contention schedule plays back
+interference — the full control path (probe -> EWMA -> tier ->
+rebalance) is exercised identically.
 """
 
 from __future__ import annotations
@@ -25,10 +27,12 @@ import dataclasses
 import time
 from typing import Callable, Dict, List, Optional
 
+import jax
 import numpy as np
 
 from repro.core.cas import TierTracker
-from repro.launch.mesh import HBM_BW
+from repro.kernels.cache_probe.ops import measure_hbm_bandwidth
+from repro.launch.mesh import HBM_BW, hbm_peak_bytes_per_s
 
 
 @dataclasses.dataclass
@@ -83,15 +87,15 @@ class PodMonitor:
         for d in range(self.n_devices):
             if self.clock is not None:
                 dt = self.clock.probe_time(d, nominal_s)
+                eff = self.probe_bytes / max(dt, 1e-12)
+                slow = max(1.0, dt / nominal_s)
                 t = self.clock.t
-            else:  # real hardware: time the actual triad kernel
-                from repro.kernels.cache_probe.ops import \
-                    measure_hbm_bandwidth
-                bw, dt = measure_hbm_bandwidth(self.probe_bytes, reps=1)
+            else:  # real hardware: time the actual triad kernel on chip d
+                dev = jax.local_devices()[d]
+                with jax.default_device(dev):
+                    eff, _ = measure_hbm_bandwidth(self.probe_bytes, reps=1)
+                slow = max(1.0, hbm_peak_bytes_per_s(dev.device_kind) / eff)
                 t = time.time()
-            eff = self.probe_bytes / max(dt, 1e-12)
-            slow = max(1.0, HBM_BW / eff) if self.clock is None else \
-                max(1.0, dt / nominal_s)
             samples.append(ProbeSample(device=d, effective_bw=eff,
                                        slowdown=slow, t=t))
         slows = np.array([s.slowdown for s in samples])

@@ -159,3 +159,27 @@ def test_cotenant_traffic_routes_to_its_domain():
     occ1 = cachesim.llc_occupancy(host.state, domain=1).sum()
     assert occ1 > 0
     assert occ0 == 0
+
+
+def test_donating_engines_consume_the_callers_state():
+    """`access_stream` and `access_streams_committed` donate the machine
+    state.  The CPU backend deletes donated buffers just as the TPU does,
+    so a caller that kept a state it passed in fails here too."""
+    geom = MachineGeometry(n_domains=1, cores_per_domain=1,
+                           l2=CacheGeometry(n_sets=16, n_ways=4),
+                           llc=CacheGeometry(n_sets=32, n_ways=4, n_slices=1))
+    blocks = jnp.arange(8, dtype=jnp.int32)
+    state = cachesim.init_machine(geom)
+    new, _ = cachesim.access_stream(state, geom, blocks,
+                                    jnp.zeros(8, jnp.int32),
+                                    jnp.zeros(8, bool))
+    assert state["l2"][0].is_deleted() and not new["l2"][0].is_deleted()
+    stacked = cachesim.stack_states([new, cachesim.init_machine(geom)])
+    out, _ = cachesim.access_streams_committed(
+        stacked, geom, jnp.stack([blocks] * 2), jnp.zeros((2, 8), jnp.int32),
+        jnp.zeros((2, 8), bool))
+    assert stacked["llc"][0].is_deleted()
+    # the stacked copy left the per-guest state intact
+    assert not new["l2"][0].is_deleted()
+    assert int(out["clock"][0]) == int(out["clock"][1]) + 8
+

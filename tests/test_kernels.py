@@ -6,8 +6,9 @@ import numpy as np
 import pytest
 from tests._hypothesis_compat import given, settings, st
 
+from repro.kernels import _compat
 from repro.kernels.cache_probe import ops as probe_ops, ref as probe_ref
-from repro.kernels.cache_probe.kernel import triad
+from repro.kernels.cache_probe.kernel import TRIAD_BLOCK, triad
 from repro.kernels.cachesim_step import ops as sim_ops, ref as sim_ref
 from repro.kernels.flash_attention import ops as fa_ops, ref as fa_ref
 from repro.kernels.ssd_scan import ops as ssd_ops, ref as ssd_ref
@@ -192,8 +193,9 @@ def test_lru_kernel_parity_sweep(rows, ways, T, seed):
 @pytest.mark.parametrize("lanes,ways,T,seed", [
     (8, 4, 24, 0),
     (16, 8, 40, 1),
-    (64, 8, 40, 2),     # multi-block grid
+    (64, 8, 40, 2),
     (32, 16, 12, 3),    # short prime, high associativity
+    (384, 11, 20, 4),   # multi-block grid (3 x 128 lanes), Table 1 ways
 ])
 def test_prime_probe_kernel_parity(lanes, ways, T, seed):
     """Batched Prime+Probe verdict kernel vs ref.py oracle (interpret mode),
@@ -246,3 +248,29 @@ def test_triad_kernel(rows, block):
 def test_measure_bandwidth_runs():
     bw, dt = probe_ops.measure_hbm_bandwidth(n_bytes=3 * (1 << 18), reps=1)
     assert bw > 0 and dt > 0
+
+
+@pytest.mark.parametrize("mib", [64, 256])
+def test_measure_bandwidth_rows_are_whole_triad_blocks(mib):
+    """PodMonitor's probe sizes: rows must be a whole number of triad
+    blocks or the kernel's grid assertion trips."""
+    rows = probe_ops.triad_rows(mib << 20)
+    assert rows % TRIAD_BLOCK == 0 and rows * 128 * 12 <= mib << 20
+    bw, dt = probe_ops.measure_hbm_bandwidth(n_bytes=mib << 20, reps=1)
+    assert bw > 0 and dt > 0
+
+
+@pytest.mark.parametrize("n,block", [(2048, 256), (384, 128), (64, 64),
+                                     (200, 200)])
+def test_lane_block_is_128_multiple_or_whole(n, block):
+    assert _compat.lane_block(n) == block
+
+
+def test_interpret_mode_only_on_cpu(monkeypatch):
+    monkeypatch.setattr(_compat.jax, "default_backend", lambda: "cpu")
+    assert _compat.interpret_mode() is True
+    monkeypatch.setattr(_compat.jax, "default_backend", lambda: "tpu")
+    assert _compat.interpret_mode() is False
+    monkeypatch.setattr(_compat.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        _compat.interpret_mode()

@@ -40,6 +40,13 @@ def test_monitor_detects_contention_and_tiers_commit():
     assert mon.slow_devices() == [2]
 
 
+def test_hbm_peak_is_keyed_by_device_kind():
+    from repro.launch.mesh import hbm_peak_bytes_per_s
+    assert hbm_peak_bytes_per_s("TPU v5 lite") == 819e9
+    with pytest.raises(KeyError, match="cpu"):
+        hbm_peak_bytes_per_s("cpu")
+
+
 def test_monitor_probe_autoshrink():
     mon = PodMonitor(n_devices=2, clock=SimClock(lambda d, t: 4.0))
     d0 = mon.probe_bytes
