@@ -27,7 +27,8 @@ the same fleet run as one group (the script steers that one run by
 patching the visible device count; the program has no such option).
 
 Lines starting ``[bring-up]`` give each phase's wall time, XLA compiles,
-persistent-cache hits and device ``peak_bytes_in_use``: they describe one
+persistent-cache hits and device ``peak_bytes_in_use`` (the counters of
+``benchmarks/chip/harness.py``): they describe one
 bring-up run and are not a benchmark.  The last line of standard output is
 the JSON result ``{"ok": true, "device": {...}}``.
 """
@@ -61,39 +62,8 @@ FOUR_CHIP_GUESTS = 256
 FOUR_CHIP_LOOP = dict(n_intervals=1, warmup=0, stream_len=64, ws_pages=4)
 
 
-class Counters:
-    """XLA compiles and persistent-cache hits seen by this process."""
-
-    def __init__(self, jax):
-        self.compile_requests = 0
-        self.cache_hits = 0
-
-        def on_duration(event, duration, **kw):
-            if event == "/jax/core/compile/backend_compile_duration":
-                self.compile_requests += 1
-
-        def on_event(event, **kw):
-            if event == "/jax/compilation_cache/cache_hits":
-                self.cache_hits += 1
-
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
-        jax.monitoring.register_event_listener(on_event)
-
-    def snapshot(self):
-        # a backend compile request served from the persistent cache is
-        # not a compile
-        return self.compile_requests - self.cache_hits, self.cache_hits
-
-
-def peak_bytes(jax):
-    out = {}
-    for d in jax.local_devices():
-        stats = d.memory_stats() or {}
-        out[d.id] = stats.get("peak_bytes_in_use")
-    return out
-
-
 def run_phase(name, fn, jax, counters):
+    from benchmarks.chip.harness import peak_bytes
     c0, h0 = counters.snapshot()
     t0 = time.perf_counter()
     fn()
@@ -288,6 +258,7 @@ def phase_kernels(rows=KERNEL_ROWS, T=KERNEL_T):
 
 def phase_sharded_four(n_guests=FOUR_CHIP_GUESTS):
     import jax
+    from benchmarks.chip.harness import peak_bytes
     from repro.core import fleetshard
     from repro.core.fleet import ShardedFleet
     check(len(jax.local_devices()) == 4,
@@ -322,12 +293,13 @@ def main() -> int:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     args = ap.parse_args()
 
-    sys.path.insert(0, os.path.join(HERE, "src"))
+    sys.path[:0] = [os.path.join(HERE, "src"), HERE]
     try:
+        from benchmarks.chip.harness import Counters
         from repro.launch.compile_cache import enable_compile_cache
     except ImportError as e:
-        print(f"chip_smoke: the repro package is not next to this script "
-              f"({e})", file=sys.stderr)
+        print(f"chip_smoke: the repo is not next to this script ({e})",
+              file=sys.stderr)
         return 2
     import jax
 

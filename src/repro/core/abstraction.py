@@ -55,7 +55,7 @@ from repro.core.color import VCOL, ColorFilters, color_accuracy
 from repro.core.eviction import C_POOL_SCALE, VEV, EvictionSet, build_many
 from repro.core.host_model import GuestVM
 from repro.core.platforms import CachePlatform, get_platform
-from repro.core import probeplan
+from repro.core import probeplan, trace
 from repro.core.probeplan import PlanLowering, PlanResult, ProbePlan
 from repro.core.shield import AttackSignal, CacheShield
 from repro.core.vscan import (DEFAULT_WINDOW_MS, DriftSignal, VScan,
@@ -458,11 +458,12 @@ class CacheXSession:
             from repro.core.backend import get_backend
             return get_backend(backend).attach(vm, platform, config=config,
                                                eager=eager)
-        session = cls(vm, platform, config)
-        if eager:
-            session.colors()
-            session.topology()
-            session.monitored_sets()
+        with trace.span("session:attach"):
+            session = cls(vm, platform, config)
+            if eager:
+                session.colors()
+                session.topology()
+                session.monitored_sets()
         return session
 
     # -- stage ensures -------------------------------------------------------
@@ -577,7 +578,8 @@ class CacheXSession:
     def topology(self) -> TopologyView:
         """Domains / effective ways / detected associativity (probes the
         VEV stage on first call)."""
-        self._ensure_topology()
+        with trace.span("session:topology"):
+            self._ensure_topology()
         plat = self.platform
         return TopologyView(
             n_domains=plat.n_domains,
@@ -596,7 +598,8 @@ class CacheXSession:
 
     def colors(self) -> ColorsView:
         """Virtual-color queries (builds the VCOL filters on first call)."""
-        self._ensure_colors()
+        with trace.span("session:colors"):
+            self._ensure_colors()
         return ColorsView(self)
 
     def llc_sets(self) -> List[EvictionSet]:
@@ -608,7 +611,8 @@ class CacheXSession:
         """VSCAN's monitored-set list (builds the VSCAN stage on first
         call).  Read-only metadata for experiment harnesses; mutating it
         desynchronizes the monitor."""
-        self._ensure_vscan()
+        with trace.span("session:monitored_sets"):
+            self._ensure_vscan()
         return list(self._vs.monitored)
 
     def contention(self, max_age_ms: Optional[float] = None) -> ContentionView:

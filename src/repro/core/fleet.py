@@ -73,7 +73,7 @@ from repro.core.host_model import (CotenantWorkload, HostEvent,
                                    shard_slices)
 from repro.core.platforms import (AttackSpec, CachePlatform, DriftSpec,
                                   get_platform)
-from repro.core import probeplan
+from repro.core import probeplan, trace
 from repro.core.probeplan import (Commit, Measure, ProbePlan, Segment,
                                   WarmTimer)
 from repro.core.runner import dataclass_csv_header, dataclass_csv_row
@@ -915,7 +915,14 @@ class FleetSim:
         executions.  With ``use_plans=False`` (or the seed
         ``use_batch=False`` reference) nothing is yielded: the loop runs
         the pre-plan per-dispatch calls inline (parity reference).
-        Returns the :class:`FleetReport`."""
+        Returns the :class:`FleetReport`.
+
+        The guest's own code between two plans (view application,
+        placement, CAP allocation, co-tenant retargeting) is traced as
+        ``fleet:decide``."""
+        return trace.spanned(self._steps(), "fleet:decide")
+
+    def _steps(self):
         t0 = time.perf_counter()
         plat, vm, tasks = self.plat, self.vm, self.tasks
         vcpus = sorted(self.vcpu_domain)

@@ -43,7 +43,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import cachesim
+from repro.core import cachesim, trace
 from repro.core.cachesim import (BLOCKS_PER_PAGE, LAT_DRAM, MachineGeometry,
                                  PAGE_BITS)
 
@@ -63,21 +63,31 @@ _BATCH_BUCKET = 8     # pad batched-probe batch dim (B) to multiples of this
 # random replacement the machine rng advances per step, padded steps
 # included, so their padding is part of the replayed sequence.
 
-# Physical probe-dispatch accounting: one count per jitted access-stream
-# call issued on behalf of guest probing (untimed, timed, batched, and the
-# multi-guest fused paths).  Co-tenant background traffic (`run_cotenants`)
-# is NOT counted — the metric is the cost of *measurement*, the quantity
-# the ProbePlan executor exists to minimize (`benchmarks --only plans`).
-_DISPATCH_STATS = {"probe_dispatches": 0}
+# Counters (`repro.core.trace`, always on):
+#   probe_dispatches     one per jitted access-stream call issued on behalf
+#                        of guest probing (untimed, timed, batched, and the
+#                        multi-guest fused paths): the cost of *measurement*,
+#                        the quantity the ProbePlan executor exists to
+#                        minimize (`benchmarks --only plans`);
+#   cotenant_dispatches  one per engine call of co-tenant background traffic
+#                        (`SimHost.run_cotenants`), which probe_dispatches
+#                        leaves out;
+#   device_syncs         one per blocking read of an engine's latencies back
+#                        to the host.
+# Spans: ``stage:*`` host staging, ``device:dispatch`` an engine call with
+# the uploads of its inputs, ``device:sync`` the host waiting for one.
 
 
 def probe_dispatch_count() -> int:
     """Total physical probe dispatches issued process-wide (all hosts)."""
-    return _DISPATCH_STATS["probe_dispatches"]
+    return trace.counter("probe_dispatches")
 
 
-def _count_probe_dispatch() -> None:
-    _DISPATCH_STATS["probe_dispatches"] += 1
+def _read_back(lats) -> np.ndarray:
+    """Block on an engine's latencies and copy them to the host."""
+    trace.count("device_syncs")
+    with trace.span("device:sync"):
+        return np.asarray(lats)
 
 
 def _pad_to_bucket(arr: np.ndarray, fill) -> np.ndarray:
@@ -406,27 +416,32 @@ class SimHost:
         return allb[perm], allc[perm], alll[perm]
 
     def run_cotenants(self, ms: float) -> None:
-        blocks, cores, l2_local = self._cotenant_stream(ms)
-        if len(blocks) == 0:
-            return
-        # l2_local accesses run prober-style (cotenant=False): they fill
-        # the issuing core's private L2 — the core-sharing tenant model —
-        # while plain co-tenants stay LLC-only as before
-        self._run_stream(blocks, cores=cores, cotenant=~l2_local)
+        with trace.span("cotenant"):
+            with trace.span("stage:gen"):
+                blocks, cores, l2_local = self._cotenant_stream(ms)
+            if len(blocks) == 0:
+                return
+            # l2_local accesses run prober-style (cotenant=False): they
+            # fill the issuing core's private L2 — the core-sharing tenant
+            # model — while plain co-tenants stay LLC-only as before
+            trace.count("cotenant_dispatches")
+            self._run_stream(blocks, cores=cores, cotenant=~l2_local)
 
     # -- raw stream execution -------------------------------------------------
     def _run_stream(self, blocks: np.ndarray, cores: np.ndarray,
                     cotenant: np.ndarray) -> np.ndarray:
         n = len(blocks)
-        pb = _pad_to_bucket(blocks.astype(np.int32), -1)
-        pc = _pad_to_bucket(cores.astype(np.int32), 0)
-        pt = np.zeros(len(pb), bool)
-        pt[:n] = cotenant
-        _note_shape("stream", self.geom, (len(pb),))
-        self.state, lats = cachesim.access_stream(
-            self.state, self.geom, jnp.asarray(pb), jnp.asarray(pc),
-            jnp.asarray(pt))
-        return np.asarray(lats)[:n]
+        with trace.span("stage:pad"):
+            pb = _pad_to_bucket(blocks.astype(np.int32), -1)
+            pc = _pad_to_bucket(cores.astype(np.int32), 0)
+            pt = np.zeros(len(pb), bool)
+            pt[:n] = cotenant
+            _note_shape("stream", self.geom, (len(pb),))
+        with trace.span("device:dispatch"):
+            self.state, lats = cachesim.access_stream(
+                self.state, self.geom, jnp.asarray(pb), jnp.asarray(pc),
+                jnp.asarray(pt))
+        return _read_back(lats)[:n]
 
     def _run_streams_batched(self, lanes: Sequence[np.ndarray],
                              cores: Sequence[int],
@@ -442,20 +457,23 @@ class SimHost:
         (per-platform plan-lowering hints; padding lanes/steps are no-ops).
         """
         n_lanes = len(lanes)
-        pb_lanes = _ladder(_round_up(n_lanes, batch_bucket or _BATCH_BUCKET))
-        t = _ladder(_round_up(max((len(l) for l in lanes), default=1),
-                              lane_bucket or _LANE_BUCKET))
-        blocks = np.full((pb_lanes, t), -1, np.int32)
-        lane_cores = np.zeros(pb_lanes, np.int32)
-        for i, (lane, core) in enumerate(zip(lanes, cores)):
-            blocks[i, :len(lane)] = lane
-            lane_cores[i] = core
-        _note_shape("batched", self.geom, (pb_lanes, t))
-        lats = cachesim.access_streams_batched(
-            self.state, self.geom, jnp.asarray(blocks),
-            jnp.asarray(lane_cores), jnp.zeros(pb_lanes, bool),
-            jnp.uint32(salt))
-        lats = np.asarray(lats)
+        with trace.span("stage:pad"):
+            pb_lanes = _ladder(_round_up(n_lanes,
+                                         batch_bucket or _BATCH_BUCKET))
+            t = _ladder(_round_up(max((len(l) for l in lanes), default=1),
+                                  lane_bucket or _LANE_BUCKET))
+            blocks = np.full((pb_lanes, t), -1, np.int32)
+            lane_cores = np.zeros(pb_lanes, np.int32)
+            for i, (lane, core) in enumerate(zip(lanes, cores)):
+                blocks[i, :len(lane)] = lane
+                lane_cores[i] = core
+            _note_shape("batched", self.geom, (pb_lanes, t))
+        with trace.span("device:dispatch"):
+            lats = cachesim.access_streams_batched(
+                self.state, self.geom, jnp.asarray(blocks),
+                jnp.asarray(lane_cores), jnp.zeros(pb_lanes, bool),
+                jnp.uint32(salt))
+        lats = _read_back(lats)
         return [lats[i, :len(lane)] for i, lane in enumerate(lanes)]
 
 
@@ -541,12 +559,13 @@ class GuestVM:
     # -- accesses ---------------------------------------------------------------
     def access(self, gvas: np.ndarray, vcpu: int = 0) -> None:
         """Untimed accesses (MLP-style batched traversal)."""
-        gvas = np.atleast_1d(np.asarray(gvas, np.int64))
-        blocks = self._hpa_block(gvas)
+        with trace.span("stage:pad"):
+            gvas = np.atleast_1d(np.asarray(gvas, np.int64))
+            blocks = self._hpa_block(gvas)
         core = self.vcpu_cores[vcpu]
         self.stat_accesses += len(blocks)
         self.stat_passes += 1
-        _count_probe_dispatch()
+        trace.count("probe_dispatches")
         self.host._run_stream(blocks, np.full(len(blocks), core, np.int32),
                               np.zeros(len(blocks), bool))
 
@@ -559,38 +578,43 @@ class GuestVM:
         one :meth:`access` per segment in the same order — the simulator
         replays the concatenated stream access by access — at 1 dispatch
         instead of ``len(segments)``."""
-        parts = [(np.atleast_1d(np.asarray(g, np.int64)), v)
-                 for g, v in segments]
-        n = sum(len(g) for g, _ in parts)
-        if n == 0:
-            return
-        blocks = np.concatenate([self._hpa_block(g) for g, _ in parts])
-        cores = np.concatenate(
-            [np.full(len(g), self.vcpu_cores[v], np.int32)
-             for g, v in parts])
+        with trace.span("stage:pad"):
+            parts = [(np.atleast_1d(np.asarray(g, np.int64)), v)
+                     for g, v in segments]
+            n = sum(len(g) for g, _ in parts)
+            if n == 0:
+                return
+            blocks = np.concatenate([self._hpa_block(g) for g, _ in parts])
+            cores = np.concatenate(
+                [np.full(len(g), self.vcpu_cores[v], np.int32)
+                 for g, v in parts])
         self.stat_accesses += n
         self.stat_passes += 1
-        _count_probe_dispatch()
+        trace.count("probe_dispatches")
         self.host._run_stream(blocks, cores, np.zeros(n, bool))
 
     def timed_access(self, gvas: np.ndarray, vcpu: int = 0) -> np.ndarray:
         """Accesses with per-access guest-TSC latencies (noisy when cold)."""
-        gvas = np.atleast_1d(np.asarray(gvas, np.int64))
-        blocks = self._hpa_block(gvas)
+        with trace.span("stage:pad"):
+            gvas = np.atleast_1d(np.asarray(gvas, np.int64))
+            blocks = self._hpa_block(gvas)
         core = self.vcpu_cores[vcpu]
         self.stat_accesses += len(blocks)
         self.stat_passes += 1
-        _count_probe_dispatch()
+        trace.count("probe_dispatches")
         lats = self.host._run_stream(
             blocks, np.full(len(blocks), core, np.int32),
             np.zeros(len(blocks), bool)).astype(np.int64)
         # Guest TSC instability (§3.1): readings spike until the timer has
         # been read a few times in quick succession; any idle period
         # (wait_ms) makes it cold again.  warm_timer() = dummy reads.
-        for i in range(len(lats)):
-            if self._timer_warm < self.timer_warm_reads and self.rng.random() < 0.35:
-                lats[i] += self.timer_noise_lat
-            self._timer_warm = min(self.timer_warm_reads, self._timer_warm + 1)
+        with trace.span("stage:noise"):
+            for i in range(len(lats)):
+                if (self._timer_warm < self.timer_warm_reads
+                        and self.rng.random() < 0.35):
+                    lats[i] += self.timer_noise_lat
+                self._timer_warm = min(self.timer_warm_reads,
+                                       self._timer_warm + 1)
         return lats
 
     def timed_access_batch(self, gva_lists: Sequence[np.ndarray],
@@ -611,15 +635,17 @@ class GuestVM:
         traversal then keeps its own timer warm, as in the fused sequential
         path).
         """
-        lanes = [np.atleast_1d(np.asarray(g, np.int64)) for g in gva_lists]
-        if not lanes:
-            return []
-        vcpus = [vcpu] * len(lanes) if np.isscalar(vcpu) else list(vcpu)
-        blocks = [self._hpa_block(lane) for lane in lanes]
-        cores = [self.vcpu_cores[v] for v in vcpus]
+        with trace.span("stage:pad"):
+            lanes = [np.atleast_1d(np.asarray(g, np.int64))
+                     for g in gva_lists]
+            if not lanes:
+                return []
+            vcpus = [vcpu] * len(lanes) if np.isscalar(vcpu) else list(vcpu)
+            blocks = [self._hpa_block(lane) for lane in lanes]
+            cores = [self.vcpu_cores[v] for v in vcpus]
         self.stat_accesses += sum(len(b) for b in blocks)
         self.stat_passes += 1
-        _count_probe_dispatch()
+        trace.count("probe_dispatches")
         out = [l.astype(np.int64)
                for l in self.host._run_streams_batched(
                    blocks, cores, salt=self._next_salt(salt),
@@ -636,13 +662,15 @@ class GuestVM:
         """Guest-TSC noise for one batched measurement (in place): each
         lane starts from the current warm level; the batch leaves the
         timer warm (shared by the single- and multi-guest batched paths)."""
-        warm0 = self._timer_warm
-        for lats in out:
-            warm = warm0
-            for i in range(min(len(lats), self.timer_warm_reads - warm0)):
-                if warm < self.timer_warm_reads and self.rng.random() < 0.35:
-                    lats[i] += self.timer_noise_lat
-                warm += 1
+        with trace.span("stage:noise"):
+            warm0 = self._timer_warm
+            for lats in out:
+                warm = warm0
+                for i in range(min(len(lats), self.timer_warm_reads - warm0)):
+                    if (warm < self.timer_warm_reads
+                            and self.rng.random() < 0.35):
+                        lats[i] += self.timer_noise_lat
+                    warm += 1
         self._timer_warm = self.timer_warm_reads
 
     def warm_timer(self) -> None:
@@ -718,40 +746,44 @@ def commit_segments_multi(vms: Sequence["GuestVM"],
     (`cachesim.access_streams_committed`).  The per-guest state evolution
     equals ``vms[i].access_segments(segments_per_vm[i])``."""
     geom = _check_multi(vms)
-    per_vm: List[Tuple[np.ndarray, np.ndarray]] = []
-    for vm, segments in zip(vms, segments_per_vm):
-        parts = [(np.atleast_1d(np.asarray(g, np.int64)), v)
-                 for g, v in segments]
-        parts = [(g, v) for g, v in parts if len(g)]
-        if parts:
-            blocks = np.concatenate([vm._hpa_block(g) for g, _ in parts])
-            cores = np.concatenate(
-                [np.full(len(g), vm.vcpu_cores[v], np.int32)
-                 for g, v in parts])
-        else:
-            blocks = np.empty(0, np.int32)
-            cores = np.empty(0, np.int32)
-        per_vm.append((blocks, cores))
-    if not any(len(b) for b, _ in per_vm):
-        return          # standalone access_segments dispatches nothing
-    t = _round_up(max(len(b) for b, _ in per_vm), _STREAM_BUCKET)
-    g_n = len(vms)
-    blocks = np.full((g_n, t), -1, np.int32)
-    cores = np.zeros((g_n, t), np.int32)
-    for i, (b, c) in enumerate(per_vm):
-        blocks[i, :len(b)] = b
-        cores[i, :len(b)] = c
-        if len(b):      # a work-free guest issues no pass standalone
-            vms[i].stat_accesses += len(b)
-            vms[i].stat_passes += 1
-    _count_probe_dispatch()
-    _note_shape("committed", geom, (g_n, t))
-    states = cachesim.stack_states([vm.host.state for vm in vms])
-    new_states, _ = cachesim.access_streams_committed(
-        states, geom, jnp.asarray(blocks), jnp.asarray(cores),
-        jnp.zeros((g_n, t), bool))
-    for vm, st in zip(vms, cachesim.unstack_states(new_states, g_n)):
-        vm.host.state = st
+    with trace.span("stage:pad"):
+        per_vm: List[Tuple[np.ndarray, np.ndarray]] = []
+        for vm, segments in zip(vms, segments_per_vm):
+            parts = [(np.atleast_1d(np.asarray(g, np.int64)), v)
+                     for g, v in segments]
+            parts = [(g, v) for g, v in parts if len(g)]
+            if parts:
+                blocks = np.concatenate([vm._hpa_block(g) for g, _ in parts])
+                cores = np.concatenate(
+                    [np.full(len(g), vm.vcpu_cores[v], np.int32)
+                     for g, v in parts])
+            else:
+                blocks = np.empty(0, np.int32)
+                cores = np.empty(0, np.int32)
+            per_vm.append((blocks, cores))
+        if not any(len(b) for b, _ in per_vm):
+            return      # standalone access_segments dispatches nothing
+        t = _round_up(max(len(b) for b, _ in per_vm), _STREAM_BUCKET)
+        g_n = len(vms)
+        blocks = np.full((g_n, t), -1, np.int32)
+        cores = np.zeros((g_n, t), np.int32)
+        for i, (b, c) in enumerate(per_vm):
+            blocks[i, :len(b)] = b
+            cores[i, :len(b)] = c
+            if len(b):  # a work-free guest issues no pass standalone
+                vms[i].stat_accesses += len(b)
+                vms[i].stat_passes += 1
+        _note_shape("committed", geom, (g_n, t))
+    trace.count("probe_dispatches")
+    with trace.span("stage:stack"):
+        states = cachesim.stack_states([vm.host.state for vm in vms])
+    with trace.span("device:dispatch"):
+        new_states, _ = cachesim.access_streams_committed(
+            states, geom, jnp.asarray(blocks), jnp.asarray(cores),
+            jnp.zeros((g_n, t), bool))
+    with trace.span("stage:unstack"):
+        for vm, st in zip(vms, cachesim.unstack_states(new_states, g_n)):
+            vm.host.state = st
 
 
 def timed_access_batch_multi(vms: Sequence["GuestVM"],
@@ -769,38 +801,43 @@ def timed_access_batch_multi(vms: Sequence["GuestVM"],
     noise draws are bit-identical to the single-guest path)."""
     geom = _check_multi(vms)
     g_n = len(vms)
-    prepared = []
-    max_b = 1
-    max_t = 1
-    for vm, gva_lists, vcpus in zip(vms, lanes_per_vm, vcpus_per_vm):
-        lanes = [np.atleast_1d(np.asarray(g, np.int64)) for g in gva_lists]
-        blocks = [vm._hpa_block(lane) for lane in lanes]
-        cores = [vm.vcpu_cores[v] for v in vcpus]
-        prepared.append((lanes, blocks, cores))
-        max_b = max(max_b, len(lanes))
-        max_t = max(max_t, max((len(l) for l in lanes), default=1))
-    if not any(lanes for lanes, _, _ in prepared):
-        return [[] for _ in vms]   # standalone path dispatches nothing
-    b_pad = _ladder(_round_up(max_b, batch_bucket or _BATCH_BUCKET))
-    t_pad = _ladder(_round_up(max_t, lane_bucket or _LANE_BUCKET))
-    blocks_arr = np.full((g_n, b_pad, t_pad), -1, np.int32)
-    cores_arr = np.zeros((g_n, b_pad), np.int32)
-    salts = np.zeros(g_n, np.uint32)
-    for i, (vm, (lanes, blocks, cores)) in enumerate(zip(vms, prepared)):
-        if not lanes:
-            continue    # empty batch: standalone early-returns untouched
-        for j, (b, c) in enumerate(zip(blocks, cores)):
-            blocks_arr[i, j, :len(b)] = b
-            cores_arr[i, j] = c
-        salts[i] = vm._next_salt(salt)
-        vm.stat_accesses += sum(len(b) for b in blocks)
-        vm.stat_passes += 1
-    _count_probe_dispatch()
-    _note_shape("batched_multi", geom, (g_n, b_pad, t_pad))
-    states = cachesim.stack_states([vm.host.state for vm in vms])
-    lats = np.asarray(cachesim.access_streams_batched_multi(
-        states, geom, jnp.asarray(blocks_arr), jnp.asarray(cores_arr),
-        jnp.zeros((g_n, b_pad), bool), jnp.asarray(salts)))
+    with trace.span("stage:pad"):
+        prepared = []
+        max_b = 1
+        max_t = 1
+        for vm, gva_lists, vcpus in zip(vms, lanes_per_vm, vcpus_per_vm):
+            lanes = [np.atleast_1d(np.asarray(g, np.int64))
+                     for g in gva_lists]
+            blocks = [vm._hpa_block(lane) for lane in lanes]
+            cores = [vm.vcpu_cores[v] for v in vcpus]
+            prepared.append((lanes, blocks, cores))
+            max_b = max(max_b, len(lanes))
+            max_t = max(max_t, max((len(l) for l in lanes), default=1))
+        if not any(lanes for lanes, _, _ in prepared):
+            return [[] for _ in vms]   # standalone path dispatches nothing
+        b_pad = _ladder(_round_up(max_b, batch_bucket or _BATCH_BUCKET))
+        t_pad = _ladder(_round_up(max_t, lane_bucket or _LANE_BUCKET))
+        blocks_arr = np.full((g_n, b_pad, t_pad), -1, np.int32)
+        cores_arr = np.zeros((g_n, b_pad), np.int32)
+        salts = np.zeros(g_n, np.uint32)
+        for i, (vm, (lanes, blocks, cores)) in enumerate(zip(vms, prepared)):
+            if not lanes:
+                continue  # empty batch: standalone early-returns untouched
+            for j, (b, c) in enumerate(zip(blocks, cores)):
+                blocks_arr[i, j, :len(b)] = b
+                cores_arr[i, j] = c
+            salts[i] = vm._next_salt(salt)
+            vm.stat_accesses += sum(len(b) for b in blocks)
+            vm.stat_passes += 1
+        _note_shape("batched_multi", geom, (g_n, b_pad, t_pad))
+    trace.count("probe_dispatches")
+    with trace.span("stage:stack"):
+        states = cachesim.stack_states([vm.host.state for vm in vms])
+    with trace.span("device:dispatch"):
+        lats = cachesim.access_streams_batched_multi(
+            states, geom, jnp.asarray(blocks_arr), jnp.asarray(cores_arr),
+            jnp.zeros((g_n, b_pad), bool), jnp.asarray(salts))
+    lats = _read_back(lats)
     results: List[List[np.ndarray]] = []
     for i, (vm, (lanes, _, _)) in enumerate(zip(vms, prepared)):
         out = [lats[i, j, :len(lane)].astype(np.int64)

@@ -40,8 +40,9 @@ tuner that measures candidate lowerings on small extracted cutouts):
     ``measure=False`` runs the same candidate scan purely on the analytic
     model (microseconds; the default for inline session use).
 
-The tuner's cutout dispatches leave no trace: both the probe-dispatch
-counter and :data:`SHAPE_CACHE` are snapshot/restored around timing, so
+The tuner's cutout dispatches leave no trace: every counter of
+`repro.core.trace` and :data:`SHAPE_CACHE` are snapshot/restored around
+timing, so
 workload dispatch accounting stays exact and tuning decisions depend only
 on what the *workload* has compiled, never on tuner history (this is what
 makes repeated tunes deterministic).
@@ -61,9 +62,10 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
-from repro.core.host_model import (_BATCH_BUCKET, _DISPATCH_STATS,
-                                   _LANE_BUCKET, _STREAM_BUCKET, _ladder,
-                                   _round_up, GuestVM, shard_slices,
+from repro.core import trace
+from repro.core.host_model import (_BATCH_BUCKET, _LANE_BUCKET,
+                                   _STREAM_BUCKET, _ladder, _round_up,
+                                   GuestVM, shard_slices,
                                    timed_access_batch_multi)
 from repro.core.probeplan import (Commit, DEFAULT_LOWERING, Measure,
                                   PlanLowering, ProbePlan, Validate, Vote)
@@ -386,7 +388,7 @@ def tune_lowering(platform, plan: Optional[ProbePlan] = None,
     cache_snap = SHAPE_CACHE.snapshot()
     pred_cache = ShapeCache()
     pred_cache.restore(cache_snap)
-    dispatch_snap = dict(_DISPATCH_STATS)
+    counter_snap = trace.snapshot()["counters"]
 
     def pred_misses(cand: PlanLowering, guests: int = 1) -> int:
         return plan_cost(ref, cand, platform=platform, n_guests=guests,
@@ -520,8 +522,7 @@ def tune_lowering(platform, plan: Optional[ProbePlan] = None,
             lockstep = False
     finally:
         # tuner dispatches leave no trace (see module docstring)
-        _DISPATCH_STATS.clear()
-        _DISPATCH_STATS.update(dispatch_snap)
+        trace.restore_counters(counter_snap)
         SHAPE_CACHE.restore(cache_snap)
 
     chosen = PlanLowering(fuse_commits=fuse, lane_bucket=best_bucket,

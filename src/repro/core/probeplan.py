@@ -55,6 +55,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from repro.core import trace
 from repro.core.host_model import (GuestVM, commit_segments_sharded,
                                    timed_access_batch_sharded)
 
@@ -283,32 +284,40 @@ def _vote(vm: GuestVM, op: Union[Vote, Validate],
     return hits * 2 > op.votes
 
 
+def _run_op(vm: GuestVM, op: ProbeOp, hints: PlanLowering):
+    """One op against one guest; returns its output (``None`` for
+    output-free ops)."""
+    if isinstance(op, Commit):
+        if hints.fuse_commits:
+            vm.access_segments([(s.gvas, s.vcpu) for s in op.segments])
+        else:
+            for s in op.segments:
+                if len(s.gvas):
+                    vm.access(s.gvas, vcpu=s.vcpu)
+        return None
+    if isinstance(op, Wait):
+        vm.wait_ms(op.ms)
+        return None
+    if isinstance(op, WarmTimer):
+        vm.warm_timer()
+        return None
+    if isinstance(op, Measure):
+        return _measure(vm, op.lanes, op.vcpus, op.salt, hints)
+    if isinstance(op, (Vote, Validate)):
+        return _vote(vm, op, hints)
+    raise TypeError(f"unknown probe op {op!r}")
+
+
 def execute(vm: GuestVM, plan: ProbePlan) -> PlanResult:
     """Run one plan against one guest.  Op order is program order; every
-    batched op is one dispatch (``Vote``: one per vote round)."""
+    batched op is one dispatch (``Vote``: one per vote round).  Traced as
+    ``plan:<label>`` with one ``op:<Kind>`` span per op."""
     hints = plan.hints or DEFAULT_LOWERING
     out: List = []
-    for op in plan.ops:
-        if isinstance(op, Commit):
-            if hints.fuse_commits:
-                vm.access_segments([(s.gvas, s.vcpu) for s in op.segments])
-            else:
-                for s in op.segments:
-                    if len(s.gvas):
-                        vm.access(s.gvas, vcpu=s.vcpu)
-            out.append(None)
-        elif isinstance(op, Wait):
-            vm.wait_ms(op.ms)
-            out.append(None)
-        elif isinstance(op, WarmTimer):
-            vm.warm_timer()
-            out.append(None)
-        elif isinstance(op, Measure):
-            out.append(_measure(vm, op.lanes, op.vcpus, op.salt, hints))
-        elif isinstance(op, (Vote, Validate)):
-            out.append(_vote(vm, op, hints))
-        else:
-            raise TypeError(f"unknown probe op {op!r}")
+    with trace.span("plan:", plan.label):
+        for op in plan.ops:
+            with trace.span("op:", type(op).__name__):
+                out.append(_run_op(vm, op, hints))
     return PlanResult(values=tuple(out))
 
 
@@ -408,7 +417,8 @@ def execute_many(vms: Sequence[GuestVM],
     A ``PlanLowering.shard_size`` hint shards the group: each batched op
     issues one multi-guest dispatch per ``shard_size`` guests (the
     rack-scale lowering — `repro.core.fleetshard`) instead of one for the
-    whole group; results stay bit-identical at any shard size."""
+    whole group; results stay bit-identical at any shard size.  Traced as
+    ``plan:<label>`` of the first plan, one ``op:<Kind>`` span per op."""
     if len(vms) != len(plans):
         raise ValueError("one plan per guest")
     if not plans:
@@ -422,55 +432,59 @@ def execute_many(vms: Sequence[GuestVM],
                              f"plans: {sig} vs {p.signature()}")
     hints = plans[0].hints or DEFAULT_LOWERING
     vms = list(vms)
-    shard = hints.shard_size
     outs: List[List] = [[] for _ in plans]
-    for j, sig_kind in enumerate(sig):
-        kind = sig_kind.split("[", 1)[0]   # strip the level suffix
-        ops = [p.ops[j] for p in plans]
-        if kind == "Commit":
-            commit_segments_sharded(
-                vms, [[(s.gvas, s.vcpu) for s in op.segments]
-                      for op in ops], shard_size=shard)
-            for o in outs:
-                o.append(None)
-        elif kind == "Wait":
-            for vm, op in zip(vms, ops):
-                vm.wait_ms(op.ms)
-            for o in outs:
-                o.append(None)
-        elif kind == "WarmTimer":
-            for vm in vms:
-                vm.warm_timer()
-            for o in outs:
-                o.append(None)
-        elif kind == "Measure":
-            if any(op.salt != ops[0].salt for op in ops):
-                raise ValueError("cannot co-execute Measures with "
-                                 "different salts")
-            res = timed_access_batch_sharded(
-                vms, [op.lanes for op in ops], [op.vcpus for op in ops],
-                salt=ops[0].salt, lane_bucket=hints.lane_bucket,
-                batch_bucket=hints.batch_bucket, shard_size=shard)
+    with trace.span("plan:", plans[0].label):
+        for j, sig_kind in enumerate(sig):
+            kind = sig_kind.split("[", 1)[0]   # strip the level suffix
+            with trace.span("op:", kind):
+                res = _run_op_many(kind, vms, [p.ops[j] for p in plans],
+                                   hints)
             for o, r in zip(outs, res):
                 o.append(r)
-        elif kind in ("Vote", "Validate"):
-            op0 = ops[0]
-            if any((op.threshold, op.votes) != (op0.threshold, op0.votes)
-                   for op in ops):
-                raise ValueError("cannot co-execute Votes with different "
-                                 "threshold/votes")
-            hits = [np.zeros(len(op.lanes), np.int64) for op in ops]
-            for vote in range(op0.votes):
-                res = timed_access_batch_sharded(
-                    vms, [op.lanes for op in ops],
-                    [op.vcpus for op in ops], salt=vote,
-                    lane_bucket=hints.lane_bucket,
-                    batch_bucket=hints.batch_bucket, shard_size=shard)
-                for h, lats, op in zip(hits, res, ops):
-                    h += np.array([int(l[-1] > op.threshold)
-                                   for l in lats], np.int64)
-            for o, h in zip(outs, hits):
-                o.append(h * 2 > op0.votes)
-        else:
-            raise TypeError(f"unknown probe op kind {kind}")
     return [PlanResult(values=tuple(o)) for o in outs]
+
+
+def _run_op_many(kind: str, vms: List[GuestVM], ops: List[ProbeOp],
+                 hints: PlanLowering) -> List:
+    """One op position of :func:`execute_many`: every guest's op of kind
+    ``kind``; returns one output per guest."""
+    shard = hints.shard_size
+    if kind == "Commit":
+        commit_segments_sharded(
+            vms, [[(s.gvas, s.vcpu) for s in op.segments] for op in ops],
+            shard_size=shard)
+        return [None] * len(vms)
+    if kind == "Wait":
+        for vm, op in zip(vms, ops):
+            vm.wait_ms(op.ms)
+        return [None] * len(vms)
+    if kind == "WarmTimer":
+        for vm in vms:
+            vm.warm_timer()
+        return [None] * len(vms)
+    if kind == "Measure":
+        if any(op.salt != ops[0].salt for op in ops):
+            raise ValueError("cannot co-execute Measures with "
+                             "different salts")
+        return timed_access_batch_sharded(
+            vms, [op.lanes for op in ops], [op.vcpus for op in ops],
+            salt=ops[0].salt, lane_bucket=hints.lane_bucket,
+            batch_bucket=hints.batch_bucket, shard_size=shard)
+    if kind in ("Vote", "Validate"):
+        op0 = ops[0]
+        if any((op.threshold, op.votes) != (op0.threshold, op0.votes)
+               for op in ops):
+            raise ValueError("cannot co-execute Votes with different "
+                             "threshold/votes")
+        hits = [np.zeros(len(op.lanes), np.int64) for op in ops]
+        for vote in range(op0.votes):
+            res = timed_access_batch_sharded(
+                vms, [op.lanes for op in ops],
+                [op.vcpus for op in ops], salt=vote,
+                lane_bucket=hints.lane_bucket,
+                batch_bucket=hints.batch_bucket, shard_size=shard)
+            for h, lats, op in zip(hits, res, ops):
+                h += np.array([int(l[-1] > op.threshold)
+                               for l in lats], np.int64)
+        return [h * 2 > op0.votes for h in hits]
+    raise TypeError(f"unknown probe op kind {kind}")

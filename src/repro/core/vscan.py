@@ -39,9 +39,10 @@ CAP's harvest tier without perturbing the LLC contention signal.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from math import comb
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -67,6 +68,8 @@ DRIFT_FRAC = 0.2
 #: Consecutive anomalous intervals before a set becomes a drift suspect
 #: (same debounce philosophy as CAS's 3-interval tier hysteresis).
 DRIFT_INTERVALS = 3
+#: Snapshots `VScan.history` keeps (the latest ones).
+HISTORY_LEN = 8
 
 
 @dataclasses.dataclass(frozen=True)
@@ -159,7 +162,9 @@ class VScan:
         self.use_plans = use_plans
         self.lowering = lowering
         self.ewma = np.zeros(len(monitored))
-        self.history: List[VScanSnapshot] = []
+        # the latest snapshots only: a session monitors for its lifetime
+        self.history: Deque[VScanSnapshot] = collections.deque(
+            maxlen=HISTORY_LEN)
 
     # -- construction pipeline (Fig 6) ----------------------------------------
     @classmethod
