@@ -50,6 +50,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import trace
+
 LINE_BITS = 6   # 64-byte cache lines
 PAGE_BITS = 12  # 4 kB pages
 BLOCKS_PER_PAGE = 1 << (PAGE_BITS - LINE_BITS)  # 64
@@ -257,15 +259,31 @@ def access_streams_committed(states, geom: MachineGeometry, blocks, cores,
             states, blocks, cores, cotenant)
 
 
-def stack_states(states):
-    """Stack per-guest machine states into one pytree with a leading guest
-    axis (host-side helper for the multi-guest dispatch paths)."""
+@jax.jit
+def _stack(states):
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *states)
 
 
+@functools.partial(jax.jit, static_argnums=(1,))
+def _unstack(states, n):
+    return tuple(jax.tree_util.tree_map(lambda x: x[i], states)
+                 for i in range(n))
+
+
+def stack_states(states):
+    """Stack per-guest machine states into one pytree with a leading guest
+    axis (host-side helper for the multi-guest dispatch paths): one
+    compiled program per call, per guest count and geometry.  The inputs
+    are copied, not donated, so the per-guest states stay usable."""
+    trace.count("staging_dispatches")
+    return _stack(states)
+
+
 def unstack_states(states, n: int):
-    """Split a stacked machine-state pytree back into per-guest states."""
-    return [jax.tree_util.tree_map(lambda x: x[i], states) for i in range(n)]
+    """Split a stacked machine-state pytree back into ``n`` per-guest
+    states, in one compiled program per call."""
+    trace.count("staging_dispatches")
+    return list(_unstack(states, n))
 
 
 # Per-lane rng fork for the batched engine.  Lane 0 keeps the machine rng

@@ -73,7 +73,9 @@ _BATCH_BUCKET = 8     # pad batched-probe batch dim (B) to multiples of this
 #                        (`SimHost.run_cotenants`), which probe_dispatches
 #                        leaves out;
 #   device_syncs         one per blocking read of an engine's latencies back
-#                        to the host.
+#                        to the host;
+#   staging_dispatches   one per `cachesim.stack_states` / `unstack_states`
+#                        program call (the multi-guest paths' staging).
 # Spans: ``stage:*`` host staging, ``device:dispatch`` an engine call with
 # the uploads of its inputs, ``device:sync`` the host waiting for one.
 

@@ -23,11 +23,13 @@ Covers:
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import cachesim, probeplan
 from repro.core.abstraction import ProbeConfig
+from repro.core.cachesim import CacheGeometry, MachineGeometry
 from repro.core.eviction import VEV, _majority_verdicts, _probe_lanes
 from repro.core.host_model import probe_dispatch_count
 from repro.core.platforms import get_platform, list_platforms
@@ -248,13 +250,80 @@ def test_fleet_seed_unbatched_reference_keeps_per_dispatch_route():
     assert FleetSim(FAST_PLATFORM, n_intervals=0)._plan_route
 
 
-def test_stack_unstack_states_roundtrip():
-    (h1, _), (h2, _) = _twin_vms(seed=27)
-    h2.state["clock"] = h2.state["clock"] + 7
-    stacked = cachesim.stack_states([h1.state, h2.state])
-    back = cachesim.unstack_states(stacked, 2)
-    _states_equal(back[0], h1.state)
-    _states_equal(back[1], h2.state)
+def _distinct_states(geom, g):
+    """g machine states of ``geom`` that differ in every leaf: each guest
+    ran its own stream (one stream length, so one compile), then had its
+    clock and rng moved by its index."""
+    states = []
+    for i in range(g):
+        blocks = jnp.arange(8, dtype=jnp.int32) * (i + 3)
+        st, _ = cachesim.access_stream(
+            cachesim.init_machine(geom), geom, blocks,
+            jnp.full(8, i % geom.n_cores, jnp.int32), jnp.zeros(8, bool))
+        st["clock"] = st["clock"] + i
+        st["rng"] = st["rng"] ^ jnp.uint32(i)
+        states.append(st)
+    return states
+
+
+def _leaves_identical(got, want):
+    """Same tree, and per leaf the same dtype, shape and values."""
+    got_leaves, got_def = jax.tree_util.tree_flatten(got)
+    want_leaves, want_def = jax.tree_util.tree_flatten(want)
+    assert got_def == want_def
+    for a, b in zip(got_leaves, want_leaves):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(a, b)
+
+
+# milan_ccx's L3 is non-inclusive, skylake_sp's inclusive
+@pytest.mark.parametrize("platform,g", [
+    pytest.param(None, 2, id="twins")] + [
+    pytest.param(name, g, id=f"{name}-{g}")
+    for name in ("milan_ccx", "skylake_sp") for g in (1, 2, 32)])
+def test_stack_unstack_states_roundtrip(platform, g):
+    """The compiled stack and unstack are exact copies: leaf by leaf, dtype
+    included, they equal ``np.stack`` and numpy slicing of the inputs."""
+    if platform is None:     # two twin VMs, one clock bumped
+        (h1, _), (h2, _) = _twin_vms(seed=27)
+        h2.state["clock"] = h2.state["clock"] + 7
+        states = [h1.state, h2.state]
+    else:
+        states = _distinct_states(get_platform(platform).machine(), g)
+    host = [jax.tree_util.tree_map(np.asarray, s) for s in states]
+    want = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *host)
+    stacked = cachesim.stack_states(states)
+    _leaves_identical(stacked, want)
+    back = cachesim.unstack_states(stacked, g)
+    assert len(back) == g
+    for i in range(g):
+        _leaves_identical(back[i],
+                          jax.tree_util.tree_map(lambda x: x[i], want))
+        _leaves_identical(back[i], host[i])
+        _states_equal(back[i], states[i])   # the inputs were not donated
+
+
+def test_staging_compiles_once_per_guest_count_and_geometry():
+    # a geometry no other test uses, so every entry here is this test's
+    geom = MachineGeometry(n_domains=1, cores_per_domain=3,
+                           l2=CacheGeometry(n_sets=8, n_ways=3),
+                           llc=CacheGeometry(n_sets=16, n_ways=5))
+    sizes = lambda: (cachesim._stack._cache_size(),
+                     cachesim._unstack._cache_size())
+
+    def roundtrip(g):
+        states = _distinct_states(geom, g)
+        cachesim.unstack_states(cachesim.stack_states(states), g)
+
+    s0 = sizes()
+    roundtrip(3)
+    s1 = sizes()
+    assert s1 == (s0[0] + 1, s0[1] + 1)
+    roundtrip(3)                        # same G and geometry: no entry
+    assert sizes() == s1
+    roundtrip(4)                        # a new G: exactly one more each
+    assert sizes() == (s1[0] + 1, s1[1] + 1)
 
 
 # ---------------------------------------------------------------------------

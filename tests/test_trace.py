@@ -8,8 +8,9 @@ Covers:
     interval list, and `spanned` holding no span across a yield;
   * the counters: ``probe_dispatches`` deltas as before (plan dispatch
     counts; co-tenant traffic left out), ``cotenant_dispatches`` one per
-    monitor ``Wait`` of a fleet interval, a measured tune leaving every
-    counter as it was;
+    monitor ``Wait`` of a fleet interval, ``staging_dispatches`` one per
+    stack or unstack program of the multi-guest paths, a measured tune
+    leaving every counter as it was;
   * tracing changes no result: a small fleet's reports and an attach's
     views are bit-identical with tracing on and off.
 """
@@ -220,6 +221,34 @@ def test_probe_dispatch_deltas_unchanged(on):
         assert spans["device:sync"]["count"] == 6
         assert spans["cotenant"]["count"] == 1
         assert spans["stage:gen"]["count"] == 1
+
+
+def _staged(fn, *args, **kw):
+    """``staging_dispatches`` added by ``fn(*args, **kw)``."""
+    s0 = trace.counter("staging_dispatches")
+    fn(*args, **kw)
+    return trace.counter("staging_dispatches") - s0
+
+
+def test_staging_dispatches_per_multi_guest_call():
+    from repro.core.host_model import (commit_segments_multi,
+                                       timed_access_batch_multi)
+    from repro.core.probeplan import Commit, ProbePlan, Segment
+    vms = [make_vm(seed=s)[1] for s in (41, 42, 43, 44, 45)]
+    lines = [np.array([vm.gva(p, 0) for p in range(8)]) for vm in vms]
+    # a Commit with work: one stack, one unstack
+    assert _staged(commit_segments_multi, vms[:2],
+                   [[(l, 0)] for l in lines[:2]]) == 2
+    # none: nothing is staged
+    assert _staged(commit_segments_multi, vms[:2], [[], []]) == 0
+    # a Measure stacks once and reads latencies back, unstacking nothing
+    assert _staged(timed_access_batch_multi, vms[:3],
+                   [[l] for l in lines[:3]], [[0]] * 3) == 1
+    # a sharded Commit in execute_many: two per shard, [2, 2, 1] here
+    plans = [ProbePlan(ops=(Commit(segments=(Segment(l, 0),)),),
+                       hints=probeplan.PlanLowering(shard_size=2))
+             for l in lines]
+    assert _staged(probeplan.execute_many, vms, plans) == 2 * 3
 
 
 def test_measured_tune_leaves_every_counter_unchanged():
