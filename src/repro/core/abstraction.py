@@ -637,11 +637,12 @@ class CacheXSession:
         On the default config this is exactly ``execute(plan())``: the
         interval compiles to a ProbePlan and runs through the one
         executor; pre-plan configs keep the direct `monitor_once` route."""
-        self._ensure_vscan()
-        if self.config.use_plans and self.config.use_batch:
-            plan = self.plan()
-            return self.apply(plan, probeplan.execute(self.vm, plan))
-        return self._publish(self._vs.monitor_once())
+        with trace.span("session:refresh"):
+            self._ensure_vscan()
+            if self.config.use_plans and self.config.use_batch:
+                plan = self.plan()
+                return self.apply(plan, probeplan.execute(self.vm, plan))
+            return self._publish(self._vs.monitor_once())
 
     # -- the plan surface ----------------------------------------------------
     def plan(self) -> ProbePlan:
@@ -696,7 +697,8 @@ class CacheXSession:
         :meth:`execute`."""
         if plan.label != "vscan.monitor":
             raise ValueError(f"not a monitoring plan: {plan.label!r}")
-        return self._publish(self._vs.apply_monitor(plan, result))
+        with trace.span("session:apply"):
+            return self._publish(self._vs.apply_monitor(plan, result))
 
     def _publish(self, snap: VScanSnapshot) -> ContentionView:
         self._intervals += 1

@@ -72,6 +72,7 @@ _BATCH_BUCKET = 8     # pad batched-probe batch dim (B) to multiples of this
 #   cotenant_dispatches  one per engine call of co-tenant background traffic
 #                        (`SimHost.run_cotenants`), which probe_dispatches
 #                        leaves out;
+#   cotenant_accesses    the accesses of those calls (before padding);
 #   device_syncs         one per blocking read of an engine's latencies back
 #                        to the host;
 #   staging_dispatches   one per `cachesim.stack_states` / `unstack_states`
@@ -427,6 +428,7 @@ class SimHost:
             # fill the issuing core's private L2 — the core-sharing tenant
             # model — while plain co-tenants stay LLC-only as before
             trace.count("cotenant_dispatches")
+            trace.count("cotenant_accesses", len(blocks))
             self._run_stream(blocks, cores=cores, cotenant=~l2_local)
 
     # -- raw stream execution -------------------------------------------------

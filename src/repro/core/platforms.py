@@ -190,10 +190,12 @@ class CachePlatform:
                          directory entry back-invalidates the line from the
                          domain's private L2s (see
                          :class:`~repro.core.cachesim.MachineGeometry` and
-                         `repro.core.hierarchy`).  All shipped platforms
+                         `repro.core.hierarchy`).  All registry entries
                          model the inclusive-directory design (Skylake's
-                         snoop filter); tests exercise the non-inclusive
-                         variant via ``dataclasses.replace``.
+                         snoop filter); tests, and the chip benchmark's
+                         configuration files (``benchmarks/chip/configs``,
+                         which build on a registry entry), may override it
+                         with ``dataclasses.replace``.
     ``noise``            co-tenant traffic attached at boot
                          (:class:`NoiseSpec`, resolved lazily).
     ``votes``            majority votes per eviction test — what the VM
@@ -368,7 +370,7 @@ SMALL_L2 = CacheGeometry(n_sets=256, n_ways=8)
 # whole LLC dedicated to the guest's domain.
 SKYLAKE_SP = register_platform(CachePlatform(
     name="skylake_sp",
-    description="Skylake-SP-like: sliced non-inclusive LLC, dedicated",
+    description="Skylake-SP-like: sliced LLC, inclusive directory, dedicated",
     l2=SMALL_L2,
     llc=CacheGeometry(n_sets=512, n_ways=8, n_slices=2),
     drift=(DriftSpec(at_interval=5, kind="remap", fraction=0.2,
