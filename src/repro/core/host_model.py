@@ -37,6 +37,7 @@ tests/exports, while guest-side detection must come from probing
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -70,9 +71,12 @@ _BATCH_BUCKET = 8     # pad batched-probe batch dim (B) to multiples of this
 #                        the quantity the ProbePlan executor exists to
 #                        minimize (`benchmarks --only plans`);
 #   cotenant_dispatches  one per engine call of co-tenant background traffic
-#                        (`SimHost.run_cotenants`), which probe_dispatches
-#                        leaves out;
+#                        (`SimHost.run_cotenants`, `wait_sharded`), which
+#                        probe_dispatches leaves out;
 #   cotenant_accesses    the accesses of those calls (before padding);
+#   cotenant_lockstep_streams
+#                        guest co-tenant streams that ran inside a
+#                        multi-guest co-tenant call (`wait_sharded`);
 #   device_syncs         one per blocking read of an engine's latencies back
 #                        to the host;
 #   staging_dispatches   one per `cachesim.stack_states` / `unstack_states`
@@ -779,15 +783,28 @@ def commit_segments_multi(vms: Sequence["GuestVM"],
                 vms[i].stat_passes += 1
         _note_shape("committed", geom, (g_n, t))
     trace.count("probe_dispatches")
+    _commit_rows([vm.host for vm in vms], geom, blocks, cores,
+                 np.zeros((g_n, t), bool))
+
+
+def _commit_rows(hosts: Sequence[SimHost], geom, blocks: np.ndarray,
+                 cores: np.ndarray, cotenant: np.ndarray) -> None:
+    """One `cachesim.access_streams_committed` call over the ``(rows, T)``
+    arrays: row r runs against ``hosts[r].state`` and is committed back to
+    it.  Rows past ``len(hosts)`` run on a copy of the first host's state
+    and their results are dropped."""
+    rows = len(blocks)
     with trace.span("stage:stack"):
-        states = cachesim.stack_states([vm.host.state for vm in vms])
+        states = [h.state for h in hosts]
+        states = cachesim.stack_states(
+            states + [states[0]] * (rows - len(states)))
     with trace.span("device:dispatch"):
         new_states, _ = cachesim.access_streams_committed(
             states, geom, jnp.asarray(blocks), jnp.asarray(cores),
-            jnp.zeros((g_n, t), bool))
+            jnp.asarray(cotenant))
     with trace.span("stage:unstack"):
-        for vm, st in zip(vms, cachesim.unstack_states(new_states, g_n)):
-            vm.host.state = st
+        for h, st in zip(hosts, cachesim.unstack_states(new_states, rows)):
+            h.state = st
 
 
 def timed_access_batch_multi(vms: Sequence["GuestVM"],
@@ -900,6 +917,82 @@ def timed_access_batch_sharded(vms: Sequence["GuestVM"],
             vms[sl], lanes_per_vm[sl], vcpus_per_vm[sl], salt=salt,
             lane_bucket=lane_bucket, batch_bucket=batch_bucket))
     return out
+
+
+def _in_lockstep_window(vm, host, ms: float) -> bool:
+    """Whether ``vm``'s co-tenant window of ``ms`` can run inside a
+    multi-guest call: a `GuestVM` on a `SimHost`, with no host event due
+    by the window's end (those split the window)."""
+    return (isinstance(vm, GuestVM) and isinstance(host, SimHost)
+            and ms > 0
+            and not (host.pending_events and host.pending_events[0].at_ms
+                     <= host.time_ms + ms))
+
+
+def wait_sharded(vms: Sequence["GuestVM"], ms: Sequence[float],
+                 shard_size: Optional[int] = None) -> None:
+    """G guests each wait their own ``ms[i]``: their co-tenant streams run
+    as one committed multi-guest call (`cachesim.access_streams_committed`)
+    per (shard, geometry, padded length) group, instead of one
+    `cachesim.access_stream` and one read-back per guest.  Nothing reads
+    the latencies back.
+
+    A guest that shares its host, or whose window `_in_lockstep_window`
+    rejects, waits through its own ``wait_ms``.  Every guest is visited
+    in order, and a participant advances its clock and draws its stream
+    from its host's rng exactly as `SimHost.advance` would.  Its stream
+    is padded to its own bucket,
+    as `SimHost._run_stream` pads it, because padded steps advance the
+    machine clock; a group with fewer guests than its shard has rows is
+    filled with inert all ``-1`` rows whose results are dropped, so the
+    compiled shape stays ``(shard, T)``.  Machine state, ``time_ms`` and
+    the host rng are bit-identical to ``vm.wait_ms(ms[i])`` per guest."""
+    vms = list(vms)
+    hosts = [getattr(vm, "host", None) for vm in vms]
+    per_host = collections.Counter(map(id, hosts))
+    streams: Dict[int, Tuple[np.ndarray, np.ndarray, np.ndarray]] = {}
+    for i, (vm, host, m) in enumerate(zip(vms, hosts, map(float, ms))):
+        if per_host[id(host)] > 1 or not _in_lockstep_window(vm, host, m):
+            vm.wait_ms(m)
+            continue
+        host.time_ms += m
+        with trace.span("cotenant"), trace.span("stage:gen"):
+            blocks, cores, l2_local = host._cotenant_stream(m)
+        if len(blocks):
+            trace.count("cotenant_accesses", len(blocks))
+            streams[i] = (blocks, cores, ~l2_local)
+        vm._timer_cooldown()
+    if not streams:
+        return
+    with trace.span("cotenant"):
+        for sl in shard_slices(len(vms), shard_size):
+            groups: Dict[Tuple, List[int]] = {}
+            for i in range(sl.start, sl.stop):
+                if i in streams:
+                    t = _round_up(len(streams[i][0]), _STREAM_BUCKET)
+                    groups.setdefault((hosts[i].geom, t), []).append(i)
+            for (geom, t), members in groups.items():
+                _run_cotenant_group([hosts[i] for i in members],
+                                    [streams[i] for i in members], geom,
+                                    sl.stop - sl.start, t)
+
+
+def _run_cotenant_group(hosts: List[SimHost], streams, geom, rows: int,
+                        t: int) -> None:
+    """One committed call of ``rows`` x ``t`` for the streams of
+    ``hosts`` (``len(hosts) <= rows``), each committed to its own host."""
+    with trace.span("stage:pad"):
+        blocks = np.full((rows, t), -1, np.int32)
+        cores = np.zeros((rows, t), np.int32)
+        cotenant = np.zeros((rows, t), bool)
+        for r, (b, c, f) in enumerate(streams):
+            blocks[r, :len(b)] = b
+            cores[r, :len(b)] = c
+            cotenant[r, :len(b)] = f
+        _note_shape("committed", geom, (rows, t))
+    trace.count("cotenant_dispatches")
+    trace.count("cotenant_lockstep_streams", len(hosts))
+    _commit_rows(hosts, geom, blocks, cores, cotenant)
 
 
 # -- canned co-tenant generators (paper §6 workload analogues) -----------------

@@ -57,7 +57,7 @@ import numpy as np
 
 from repro.core import trace
 from repro.core.host_model import (GuestVM, commit_segments_sharded,
-                                   timed_access_batch_sharded)
+                                   timed_access_batch_sharded, wait_sharded)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -408,8 +408,12 @@ def execute_many(vms: Sequence[GuestVM],
                  plans: Sequence[ProbePlan]) -> List[PlanResult]:
     """Run G structurally-congruent plans — one per guest, each guest on
     its own host — as ONE vectorized program: every Commit / Measure is a
-    single dispatch vmapped over guests (``Vote``: one per vote round);
-    Wait / WarmTimer apply per guest (each guest keeps its own window).
+    single dispatch vmapped over guests (``Vote``: one per vote round).
+    A Wait runs the guests' co-tenant streams, each over its own length,
+    as one committed multi-guest call per shard and padded length
+    (`host_model.wait_sharded`; a guest whose window a host event splits,
+    or a target that is no `GuestVM` on its own `SimHost`, waits alone);
+    WarmTimer applies per guest.
     Per-guest results are bit-identical to ``execute(vms[i], plans[i])``
     under deterministic (LRU) replacement — the ``PlanLowering.lockstep``
     hint gates callers accordingly.
@@ -455,8 +459,7 @@ def _run_op_many(kind: str, vms: List[GuestVM], ops: List[ProbeOp],
             shard_size=shard)
         return [None] * len(vms)
     if kind == "Wait":
-        for vm, op in zip(vms, ops):
-            vm.wait_ms(op.ms)
+        wait_sharded(vms, [op.ms for op in ops], shard_size=shard)
         return [None] * len(vms)
     if kind == "WarmTimer":
         for vm in vms:

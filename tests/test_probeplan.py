@@ -27,11 +27,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import cachesim, probeplan
+from repro.core import cachesim, probeplan, trace
 from repro.core.abstraction import ProbeConfig
 from repro.core.cachesim import CacheGeometry, MachineGeometry
 from repro.core.eviction import VEV, _majority_verdicts, _probe_lanes
-from repro.core.host_model import probe_dispatch_count
+from repro.core.host_model import (CotenantWorkload, GuestVM, SimHost,
+                                   polluter_gen, probe_dispatch_count)
+from repro.core.plancost import SHAPE_CACHE
 from repro.core.platforms import get_platform, list_platforms
 from repro.core.probeplan import (Commit, Measure, PlanLowering, ProbePlan,
                                   Segment, Vote, Wait, WarmTimer)
@@ -326,6 +328,45 @@ def test_staging_compiles_once_per_guest_count_and_geometry():
     assert sizes() == (s1[0] + 1, s1[1] + 1)
 
 
+def test_lockstep_wait_compiles_once_per_shard_and_bucket():
+    """A lockstep Wait compiles one committed program per (shard, padded
+    length); a length group smaller than its shard is filled with inert
+    rows, so it reuses the shard's shape."""
+    # a geometry no other test uses, so every entry here is this test's
+    geom = MachineGeometry(n_domains=1, cores_per_domain=2,
+                           l2=CacheGeometry(n_sets=8, n_ways=2),
+                           llc=CacheGeometry(n_sets=32, n_ways=7))
+
+    def guest(seed, rate):
+        host = SimHost(geom, n_host_pages=1 << 10, seed=seed)
+        host.add_cotenant(CotenantWorkload(
+            "noise", 0, rate, polluter_gen(region_pages=64)))
+        return GuestVM(host, n_guest_pages=64, vcpu_cores=[0, 1],
+                       seed=seed)
+
+    def wait(vms):
+        plan = ProbePlan(ops=(Wait(ms=7.0),),
+                         hints=PlanLowering(shard_size=2))
+        probeplan.execute_many(vms, [plan] * len(vms))
+
+    size = cachesim.access_streams_committed._cache_size
+    s0 = size()
+    wait([guest(s, 30.0) for s in range(4)])          # 210: (2, 512)
+    assert size() == s0 + 1
+    assert SHAPE_CACHE.seen("committed", geom, (2, 512))
+    wait([guest(s, 30.0) for s in range(10, 14)])     # same shard, bucket
+    assert size() == s0 + 1
+    # one guest of each shard pads to 1024: a group of one, filled with an
+    # inert row to the shard's (2, 1024)
+    wait([guest(20, 30.0), guest(21, 100.0), guest(22, 100.0),
+          guest(23, 30.0)])
+    assert size() == s0 + 2
+    assert SHAPE_CACHE.seen("committed", geom, (2, 1024))
+    wait([guest(30, 100.0), guest(31, 30.0), guest(32, 30.0),
+          guest(33, 100.0)])
+    assert size() == s0 + 2
+
+
 # ---------------------------------------------------------------------------
 # plan vs pre-redesign parity (property-style, per platform)
 # ---------------------------------------------------------------------------
@@ -349,11 +390,18 @@ def test_pipeline_plan_vs_legacy_parity(name):
     assert a.dispatches <= b.dispatches      # fusion never adds dispatches
 
 
+def _cotenant_counts(since=(0, 0)):
+    """(``cotenant_dispatches``, ``cotenant_accesses``) less ``since``."""
+    return (trace.counter("cotenant_dispatches") - since[0],
+            trace.counter("cotenant_accesses") - since[1])
+
+
 def test_fleet_lockstep_parity_and_dispatch_reduction():
     """The fleet acceptance property: lockstep multi-guest execution
     reproduces every report metric bit for bit vs both the sequential plan
     path and the pre-plan legacy path, while issuing >= 2x fewer physical
-    probe dispatches per tick than the legacy per-guest loop."""
+    probe dispatches per tick than the legacy per-guest loop, and G times
+    fewer co-tenant engine calls than the sequential plan path."""
     from repro.core.fleet import FleetSim, _run_lockstep
     combos = (("eevdf", "on"), ("cas", "on"), ("cas", "off"))
     kw = dict(n_intervals=6, warmup=2, seed=0)
@@ -364,14 +412,18 @@ def test_fleet_lockstep_parity_and_dispatch_reduction():
     legacy = [s.run() for s in legacy_sims]
     legacy_loop = probe_dispatch_count() - d0
 
-    seq = [FleetSim(FAST_PLATFORM, policy=p, cap=c, **kw).run()
-           for p, c in combos]
+    seq_sims = [FleetSim(FAST_PLATFORM, policy=p, cap=c, **kw)
+                for p, c in combos]
+    c0 = _cotenant_counts()
+    seq = [s.run() for s in seq_sims]
+    seq_cotenant = _cotenant_counts(c0)
 
     lock_sims = [FleetSim(FAST_PLATFORM, policy=p, cap=c, **kw)
                  for p, c in combos]
-    d0 = probe_dispatch_count()
+    d0, c0 = probe_dispatch_count(), _cotenant_counts()
     lock = _run_lockstep(lock_sims)
     lock_loop = probe_dispatch_count() - d0
+    lock_cotenant = _cotenant_counts(c0)
 
     skip = ("dispatches", "wall_s", "guests_per_sec")
     for l, s, k in zip(legacy, seq, lock):
@@ -382,3 +434,8 @@ def test_fleet_lockstep_parity_and_dispatch_reduction():
             assert getattr(s, f.name) == getattr(k, f.name), f.name
     # the acceptance ratio: physical probe dispatches per tick, whole fleet
     assert legacy_loop >= 2 * lock_loop, (legacy_loop, lock_loop)
+    # each lockstep Wait runs the G guests' co-tenant streams in one call:
+    # G times fewer co-tenant engine calls over the same accesses
+    assert lock_cotenant[1] == seq_cotenant[1] > 0
+    assert seq_cotenant[0] >= len(combos) * lock_cotenant[0] > 0, (
+        seq_cotenant, lock_cotenant)

@@ -8,7 +8,9 @@ Covers:
     interval list, and `spanned` holding no span across a yield;
   * the counters: ``probe_dispatches`` deltas as before (plan dispatch
     counts; co-tenant traffic left out), ``cotenant_dispatches`` one per
-    monitor ``Wait`` of a fleet interval, ``staging_dispatches`` one per
+    monitor ``Wait`` and guest shard of a fleet interval,
+    ``cotenant_lockstep_streams`` one per guest's stream in it,
+    ``staging_dispatches`` one per
     stack or unstack program of the multi-guest paths, a measured tune
     leaving every counter as it was;
   * tracing changes no result: a small fleet's reports and an attach's
@@ -25,7 +27,7 @@ import pytest
 
 from repro.core import CacheXSession, ProbeConfig, probeplan, trace
 from repro.core.fleet import ShardedFleet
-from repro.core.host_model import probe_dispatch_count
+from repro.core.host_model import probe_dispatch_count, shard_slices
 
 from conftest import make_vm
 
@@ -265,26 +267,31 @@ def _fleet(plat, n=8):
 
 
 def test_cotenant_dispatches_one_per_monitor_wait(tiny, monkeypatch):
+    """One co-tenant engine call per monitor ``Wait`` and guest shard: a
+    lockstep round runs every guest's stream of the shard in one call
+    (the fleet's streams pad to one length), and
+    ``cotenant_lockstep_streams`` counts each guest's stream."""
     fl = _fleet(tiny)
-    waits = []
-    execute, execute_many = probeplan.execute, probeplan.execute_many
+    waits, calls = [], []
+    execute_many = probeplan.execute_many
 
-    def counted(fn):
-        def run(vms, plans):
-            many = isinstance(plans, (list, tuple))
-            for p in plans if many else [plans]:
-                if p.label == "vscan.monitor":
-                    waits.append(p.signature().count("Wait"))
-            return fn(vms, plans)
-        return run
+    def counted(vms, plans):
+        monitor = [p.signature().count("Wait") for p in plans
+                   if p.label == "vscan.monitor"]
+        if monitor:
+            waits.extend(monitor)
+            shard = plans[0].effective_lowering().shard_size
+            calls.append(monitor[0] * len(shard_slices(len(vms), shard)))
+        return execute_many(vms, plans)
 
-    monkeypatch.setattr(probeplan, "execute", counted(execute))
-    monkeypatch.setattr(probeplan, "execute_many", counted(execute_many))
+    monkeypatch.setattr(probeplan, "execute_many", counted)
     c0 = trace.counter("cotenant_dispatches")
+    s0 = trace.counter("cotenant_lockstep_streams")
     fl.run()
     assert len(waits) == 8 * FLEET["n_intervals"]
     assert set(waits) == {1}
-    assert trace.counter("cotenant_dispatches") - c0 == sum(waits)
+    assert trace.counter("cotenant_dispatches") - c0 == sum(calls)
+    assert trace.counter("cotenant_lockstep_streams") - s0 == sum(waits)
 
 
 # -- tracing changes no result ------------------------------------------------
