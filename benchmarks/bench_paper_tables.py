@@ -11,8 +11,8 @@ Fig 11   — CAP latency improvement (vanilla / CAP / CAP+vscan)
 Fig 12   — CacheX monitoring overhead
 fleet    — Fig 10 / Tables 7-8 analogs, closed-loop: policy x platform x
            CAP sweep through the probe->decide->act->measure fleet loop
-plans    — ProbePlan executor vs the pre-plan batched baseline: physical
-           probe dispatches per fleet tick (legacy / plans / lockstep),
+plans    — ProbePlan executor, one guest at a time vs lockstep: physical
+           probe dispatches per fleet tick (plans / lockstep),
            headline-parity check, bench-plans-dispatch.csv artifact
 drift    — host-event drift scenarios: incremental `session.repair()` vs
            a from-scratch re-attach after a <=25% remap (dispatch ratio,
@@ -49,42 +49,30 @@ from repro.core.vscan import VScan, theoretical_coverage
 
 
 def bench_table2_eviction_construction():
-    """Seed per-test scan path vs the batched multi-set Prime+Probe engine
-    on the same 4-partition parallel build; the dispatch-reduction row is
-    the PR's acceptance metric (>= 5x)."""
-    stats = {}
-    for mode, use_batch in (("seed", False), ("batched", True)):
-        # two identical runs; the first warms every jit shape this mode
-        # hits, so the second measures steady-state cost
-        for _ in range(2):
-            host, vm = bench_vm(seed=1)
-            vev = VEV(vm, use_batch=use_batch)
-            parts = []
-            for i in range(4):
-                pool = vev.make_pool(64 * i, ways=8, n_uncontrollable_rows=8,
-                                     n_slices=2, scale=3)
-                parts.append({"offset": 64 * i, "pool": pool, "max_sets": 2})
-            vcpu_domain = {0: 0, 1: 0}
-            vm.stat_passes = 0
-            with timer() as t:
-                res = build_parallel(vm, parts, "llc", 8,
-                                     pair_vcpus=[(0, 1)],
-                                     vcpu_domain=vcpu_domain,
-                                     use_batch=use_batch)
-        stats[mode] = {"us": t["us"], "dispatches": vm.stat_passes,
-                       "sets": len(res.sets)}
-        emit(f"table2.vev_build_{mode}", t["us"] / max(1, len(res.sets)),
-             f"sets={len(res.sets)};fail={res.failures};"
-             f"dispatches={vm.stat_passes};"
-             f"seq_passes={res.sequential_passes};"
-             f"crit_passes={res.critical_path_passes};"
-             f"modelled_speedup={res.sequential_passes/max(1,res.critical_path_passes):.1f}x")
-    red = stats["seed"]["dispatches"] / max(1, stats["batched"]["dispatches"])
-    speed = stats["seed"]["us"] / max(1.0, stats["batched"]["us"])
-    emit("table2.batched_dispatch_reduction", 0.0,
-         f"seed_dispatches={stats['seed']['dispatches']};"
-         f"batched_dispatches={stats['batched']['dispatches']};"
-         f"reduction={red:.1f}x;wall_speedup={speed:.2f}x")
+    """The 4-partition parallel build through the batched multi-set
+    Prime+Probe engine: per-set wall time, dispatches, and the modelled
+    sequential vs critical-path passes."""
+    # two identical runs; the first warms every jit shape the build hits,
+    # so the second measures steady-state cost
+    for _ in range(2):
+        host, vm = bench_vm(seed=1)
+        vev = VEV(vm)
+        parts = []
+        for i in range(4):
+            pool = vev.make_pool(64 * i, ways=8, n_uncontrollable_rows=8,
+                                 n_slices=2, scale=3)
+            parts.append({"offset": 64 * i, "pool": pool, "max_sets": 2})
+        vcpu_domain = {0: 0, 1: 0}
+        vm.stat_passes = 0
+        with timer() as t:
+            res = build_parallel(vm, parts, "llc", 8, pair_vcpus=[(0, 1)],
+                                 vcpu_domain=vcpu_domain)
+    emit("table2.vev_build", t["us"] / max(1, len(res.sets)),
+         f"sets={len(res.sets)};fail={res.failures};"
+         f"dispatches={vm.stat_passes};"
+         f"seq_passes={res.sequential_passes};"
+         f"crit_passes={res.critical_path_passes};"
+         f"modelled_speedup={res.sequential_passes/max(1,res.critical_path_passes):.1f}x")
 
 
 def bench_table3_associativity():
@@ -149,24 +137,14 @@ def bench_table6_prime_probe():
                         domain_vcpus={0: [0]}, seed=3)
     n_sets = len(vs.monitored)
     lines_per_set = 8
-    # per-probe dispatch count: seed probes each monitored set with its own
-    # jitted call; batched fuses every set into one multi-set dispatch
-    stats = {}
-    for mode, use_batch in (("seed", False), ("batched", True)):
-        vs.use_batch = use_batch
-        vs.monitor_once()                 # warm the mode's jit shapes
-        before = vm.stat_passes
-        with timer() as t:
-            vs.monitor_once()
-        stats[mode] = {"us": t["us"], "dispatches": vm.stat_passes - before}
-        emit(f"table6.prime_probe_{mode}", t["us"],
-             f"sets={n_sets};dispatches={stats[mode]['dispatches']}")
-    red = stats["seed"]["dispatches"] / max(1, stats["batched"]["dispatches"])
-    emit("table6.batched_dispatch_reduction", 0.0,
-         f"seed_dispatches={stats['seed']['dispatches']};"
-         f"batched_dispatches={stats['batched']['dispatches']};"
-         f"reduction={red:.1f}x;"
-         f"wall_speedup={stats['seed']['us']/max(1.0, stats['batched']['us']):.2f}x")
+    # one interval fuses every monitored set's prime into one dispatch and
+    # its probe into another
+    vs.monitor_once()                     # warm the interval's jit shapes
+    before = vm.stat_passes
+    with timer() as t:
+        vs.monitor_once()
+    emit("table6.prime_probe", t["us"],
+         f"sets={n_sets};dispatches={vm.stat_passes - before}")
     for pairs in (1, 2, 4):
         # modelled: prime+probe passes divide across pairs
         crit_accesses = (n_sets * lines_per_set * 2) / pairs
@@ -353,11 +331,9 @@ def bench_fleet():
 
 def bench_plans():
     """ProbePlan acceptance bench: the closed-loop fleet (every combo a
-    co-running guest) run three ways on one platform —
+    co-running guest) run two ways on one platform —
 
-      * ``legacy``   the PR-1/PR-3 batched baseline (per-stage dispatch
-                     drivers, per-guest loops),
-      * ``plans``    ProbePlan programs, still one guest at a time,
+      * ``plans``    ProbePlan programs, one guest at a time,
       * ``lockstep`` all guests' plans co-executed per tick
                      (`probeplan.execute_many`, the `run_fleet_matrix`
                      default),
@@ -376,9 +352,8 @@ def bench_plans():
     guests = len(DEFAULT_COMBOS)
     rows = []
     reports = {}
-    for mode in ("legacy", "plans", "lockstep"):
+    for mode in ("plans", "lockstep"):
         sims = [FleetSim(plat, policy=pol, cap=cap, seed=0,
-                         use_plans=(mode != "legacy"),
                          n_intervals=n_intervals, warmup=warmup)
                 for pol, cap in DEFAULT_COMBOS]
         d0 = probe_dispatch_count()
@@ -396,18 +371,17 @@ def bench_plans():
              f"per_tick={per_tick:.1f}")
     # headline parity across modes (the bit-identity acceptance criterion)
     parity = all(
-        a.throughput == b.throughput == c.throughput
-        and a.quiet_residency == b.quiet_residency == c.quiet_residency
-        and a.ws_lat_cycles == b.ws_lat_cycles == c.ws_lat_cycles
-        for a, b, c in zip(*[reports[m]
-                             for m in ("legacy", "plans", "lockstep")]))
-    legacy_pt, lock_pt = rows[0][4], rows[2][4]
+        a.throughput == b.throughput
+        and a.quiet_residency == b.quiet_residency
+        and a.ws_lat_cycles == b.ws_lat_cycles
+        for a, b in zip(reports["plans"], reports["lockstep"]))
+    plans_pt, lock_pt = rows[0][4], rows[1][4]
     emit("plans.dispatch_reduction", 0.0,
-         f"legacy_per_tick={legacy_pt:.1f};lockstep_per_tick={lock_pt:.1f};"
-         f"reduction={legacy_pt / max(lock_pt, 1e-9):.1f}x;"
+         f"plans_per_tick={plans_pt:.1f};lockstep_per_tick={lock_pt:.1f};"
+         f"reduction={plans_pt / max(lock_pt, 1e-9):.1f}x;"
          f"headline_parity={parity}")
     record(f"fleet_loop_probe_dispatches_per_tick.{plat}.{guests}guests",
-           lock_pt, f"legacy {legacy_pt:.1f}/tick; "
+           lock_pt, f"per-guest plans {plans_pt:.1f}/tick; "
            f"headline_parity={parity}; `--only plans`")
     path = "bench-plans-dispatch.csv"
     with open(path, "w") as f:

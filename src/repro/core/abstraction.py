@@ -8,7 +8,7 @@ This module is that API for the reproduction: one :class:`CacheXSession`
 owns the VEV → VCOL → VSCAN probing lifecycle against a
 :class:`~repro.core.platforms.CachePlatform` and serves stable queries, so
 policies, drivers, benchmarks and examples never hand-wire probe
-constructors or thread ``votes``/``prime_reps``/``use_batch`` parameters
+constructors or thread ``votes``/``prime_reps``/``lowering`` parameters
 again (the Com-CAS / CacheShield design point: a cache-state interface
 between probing and policy).
 
@@ -102,12 +102,6 @@ class ProbeConfig:
     ``votes``            majority votes per eviction test (non-LRU /
                          noisy scenarios; ``CachePlatform.votes``).
     ``prime_reps``       prime repetitions per test (same rationale).
-    ``use_batch``        route probes through the fused multi-set engine
-                         (False keeps the seed per-test path for benches).
-    ``use_plans``        emit every batched probe as a ProbePlan program
-                         run by the one executor (`repro.core.probeplan`);
-                         False keeps the pre-plan per-stage dispatch
-                         drivers as the parity/benchmark reference.
     ``lowering``         ProbePlan lowering hints (padding buckets, commit
                          fusion, lockstep eligibility); platform-derived
                          via :meth:`CachePlatform.plan_lowering` in
@@ -139,8 +133,6 @@ class ProbeConfig:
 
     votes: int = 1
     prime_reps: int = 1
-    use_batch: bool = True
-    use_plans: bool = True
     lowering: Optional[PlanLowering] = None
     f: int = 2
     offsets: Tuple[int, ...] = (0,)
@@ -331,8 +323,7 @@ def _build_colors(vm: GuestVM, plat: CachePlatform,
                   cfg: ProbeConfig) -> Tuple[VCOL, ColorFilters]:
     """VCOL stage: build the platform's L2 color filters."""
     vcol = VCOL(vm, vev=VEV(vm, votes=cfg.votes, prime_reps=cfg.prime_reps,
-                            use_batch=cfg.use_batch,
-                            use_plans=cfg.use_plans, lowering=cfg.lowering))
+                            lowering=cfg.lowering))
     cf = vcol.build_color_filters(n_colors=plat.n_l2_colors,
                                   ways=plat.l2.n_ways, seed=cfg.seed)
     return vcol, cf
@@ -369,8 +360,7 @@ def _build_vscan(vm: GuestVM, plat: CachePlatform, vcol: VCOL,
                            prime_reps=cfg.prime_reps, seed=cfg.seed,
                            window_ms=cfg.window_ms,
                            ewma_alpha=cfg.ewma_alpha,
-                           use_batch=cfg.use_batch,
-                           use_plans=cfg.use_plans, lowering=cfg.lowering)
+                           lowering=cfg.lowering)
     if cfg.prune_self_conflicts:
         info["pruned_self_conflicts"] = vs.prune_self_conflicts()
     info["pool_pages"] = info_pool      # for drift-rebuild page recycling
@@ -487,7 +477,6 @@ class CacheXSession:
     def _vev(self) -> VEV:
         cfg = self.config
         return VEV(self.vm, votes=cfg.votes, prime_reps=cfg.prime_reps,
-                   use_batch=cfg.use_batch, use_plans=cfg.use_plans,
                    lowering=cfg.lowering)
 
     def effective_ways(self) -> int:
@@ -516,8 +505,7 @@ class CacheXSession:
         results, _, _ = build_many(
             vm, [{"offset": 0, "pool": pool, "max_sets": target}],
             "llc", ways, votes=cfg.votes, seed=cfg.seed,
-            use_batch=cfg.use_batch, prime_reps=cfg.prime_reps,
-            use_plans=cfg.use_plans, lowering=cfg.lowering)
+            prime_reps=cfg.prime_reps, lowering=cfg.lowering)
         self._llc_sets = results[0]
         assoc_pool = vev.make_pool(
             64, ways=ways, n_uncontrollable_rows=plat.n_llc_rows_per_offset,
@@ -634,15 +622,12 @@ class CacheXSession:
     def refresh(self) -> ContentionView:
         """Run one monitoring interval now and publish it to subscribers.
 
-        On the default config this is exactly ``execute(plan())``: the
-        interval compiles to a ProbePlan and runs through the one
-        executor; pre-plan configs keep the direct `monitor_once` route."""
+        This is exactly ``execute(plan())``: the interval compiles to a
+        ProbePlan and runs through the one executor."""
         with trace.span("session:refresh"):
             self._ensure_vscan()
-            if self.config.use_plans and self.config.use_batch:
-                plan = self.plan()
-                return self.apply(plan, probeplan.execute(self.vm, plan))
-            return self._publish(self._vs.monitor_once())
+            plan = self.plan()
+            return self.apply(plan, probeplan.execute(self.vm, plan))
 
     # -- the plan surface ----------------------------------------------------
     def plan(self) -> ProbePlan:
@@ -1195,7 +1180,9 @@ class CacheXSession:
                     f"repair() to salvage what survived.")
         plat = get_platform(data["platform"])
         if config is None:
-            kw = dict(data["config"])
+            # a snapshot may carry knobs retired since it was written
+            known = {f.name for f in dataclasses.fields(ProbeConfig)}
+            kw = {k: v for k, v in data["config"].items() if k in known}
             kw["offsets"] = tuple(kw["offsets"])
             kw["l2_monitor_cores"] = tuple(kw.get("l2_monitor_cores", ()))
             if isinstance(kw.get("lowering"), dict):
@@ -1212,7 +1199,6 @@ class CacheXSession:
             session._cf = ColorFilters.from_state(sec["filters"])
             session._vcol = VCOL(vm, vev=VEV(
                 vm, votes=config.votes, prime_reps=config.prime_reps,
-                use_batch=config.use_batch, use_plans=config.use_plans,
                 lowering=config.lowering))
             session._page_colors = {int(p): int(c)
                                     for p, c in sec["page_colors"].items()}
@@ -1239,8 +1225,6 @@ class CacheXSession:
                 reserve.update(int(g) >> PAGE_BITS for g in es.gvas)
         if "vscan" in data:
             session._vs = VScan.from_state(vm, data["vscan"],
-                                           use_batch=config.use_batch,
-                                           use_plans=config.use_plans,
                                            lowering=config.lowering)
             for m in session._vs.monitored:
                 reserve.update(int(g) >> PAGE_BITS for g in m.es.gvas)

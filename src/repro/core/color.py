@@ -124,64 +124,41 @@ class VCOL:
           [all filters' lines]                 (prime — evicts matching lines)
           [page lines again, timed]            (probe)
 
-        With the batched probe engine (``vev.use_batch``, the default) every
-        page becomes one lane of a single fused multi-set Prime+Probe
-        dispatch — emitted as a one-op Measure ProbePlan (or the pre-plan
-        direct batched call when ``vev.use_plans`` is off); the legacy path
-        issues one fused stream per ``batch`` pages (the seed Table 4 path).
+        Every page becomes one lane of a single fused multi-set
+        Prime+Probe dispatch, emitted as a one-op Measure ProbePlan; a page
+        whose probe is ambiguous falls back to
+        :meth:`identify_color_sequential`.
         """
         pages = np.asarray(pages, np.int64)
         n_colors = cf.n_colors
         out = np.full(len(pages), -1, np.int64)
         filter_lines = np.concatenate([es.gvas for es in cf.filters])
-        if self.vev.use_batch and len(pages):
-            # one lane per `batch`-page chunk (pages in a chunk share the
-            # filter prime, exactly like the seed fused stream); all chunks
-            # ride a single dispatch
-            lanes = []
-            spans = []
-            for s in range(0, len(pages), batch):
-                chunk = pages[s:s + batch]
-                flat = np.array(
-                    [self.vm.gva(int(p), int(off)) for p in chunk
-                     for off in cf.offsets], np.int64)   # (len(chunk)*colors)
-                lanes.append(np.concatenate([flat, filter_lines, flat]))
-                spans.append((s, len(chunk), len(flat)))
-            if self.vev.use_plans:
-                plan = ProbePlan(
-                    ops=(Measure(lanes=tuple(lanes),
-                                 vcpus=(self.vcpu,) * len(lanes)),),
-                    label="vcol.identify", hints=self.vev.lowering)
-                lat_lanes = probeplan.execute(self.vm, plan).last
-            else:
-                lat_lanes = self.vm.timed_access_batch(lanes, vcpu=self.vcpu)
-            for (s, n, flen), lats in zip(spans, lat_lanes):
-                probe = lats[flen + len(filter_lines):].reshape(n, n_colors)
-                evicted = probe > L2_MISS_THRESHOLD
-                out[s:s + n] = np.argmax(probe, axis=1)
-                bad = evicted.sum(axis=1) != 1
-                for i in np.nonzero(bad)[0]:
-                    out[s + i] = self.identify_color_sequential(
-                        cf, int(pages[s + i]))
+        if not len(pages):
             return out
+        # one lane per `batch`-page chunk (pages in a chunk share the
+        # filter prime); all chunks ride a single dispatch
+        lanes = []
+        spans = []
         for s in range(0, len(pages), batch):
             chunk = pages[s:s + batch]
-            page_lines = np.stack(
-                [[self.vm.gva(int(p), int(off)) for off in cf.offsets]
-                 for p in chunk])                       # (B, n_colors)
-            flat = page_lines.reshape(-1)
-            stream = np.concatenate([flat, filter_lines, flat])
-            lats = self.vm.timed_access(stream, vcpu=self.vcpu)
-            probe = lats[len(flat) + len(filter_lines):].reshape(len(chunk),
-                                                                 n_colors)
+            flat = np.array(
+                [self.vm.gva(int(p), int(off)) for p in chunk
+                 for off in cf.offsets], np.int64)   # (len(chunk)*colors)
+            lanes.append(np.concatenate([flat, filter_lines, flat]))
+            spans.append((s, len(chunk), len(flat)))
+        plan = ProbePlan(
+            ops=(Measure(lanes=tuple(lanes),
+                         vcpus=(self.vcpu,) * len(lanes)),),
+            label="vcol.identify", hints=self.vev.lowering)
+        lat_lanes = probeplan.execute(self.vm, plan).last
+        for (s, n, flen), lats in zip(spans, lat_lanes):
+            probe = lats[flen + len(filter_lines):].reshape(n, n_colors)
             evicted = probe > L2_MISS_THRESHOLD
-            # exactly one line per page should be evicted; noise -> argmax
-            out[s:s + len(chunk)] = np.argmax(probe, axis=1)
-            # (argmax of latency == the evicted offset; ties impossible in
-            #  the quiet case, majority re-test handles noisy cases)
+            out[s:s + n] = np.argmax(probe, axis=1)
             bad = evicted.sum(axis=1) != 1
             for i in np.nonzero(bad)[0]:
-                out[s + i] = self.identify_color_sequential(cf, int(chunk[i]))
+                out[s + i] = self.identify_color_sequential(
+                    cf, int(pages[s + i]))
         return out
 
     # -- drift revalidation (recolor only what broke) ---------------------------
@@ -210,24 +187,15 @@ class VCOL:
         for i in idx:
             es = cf.filters[int(colors[i])]
             tests.append((self.vm.gva(int(pages[i]), es.offset), es.gvas))
-        if self.vev.use_batch:
-            from repro.core.eviction import _probe_lanes
-            lanes = _probe_lanes(tests, self.vev.prime_reps)
-            if self.vev.use_plans:
-                plan = ProbePlan(
-                    ops=(Vote(lanes=tuple(lanes),
-                              vcpus=(self.vcpu,) * len(lanes),
-                              threshold=L2_MISS_THRESHOLD,
-                              votes=self.vev.votes),),
-                    label="vcol.validate", hints=self.vev.lowering)
-                verdicts = probeplan.execute(self.vm, plan).last
-            else:
-                from repro.core.eviction import _majority_verdicts
-                verdicts = _majority_verdicts(self.vm, lanes, self.vcpu,
-                                              L2_MISS_THRESHOLD,
-                                              self.vev.votes)
-        else:
-            verdicts = [self.vev.evicts(t, c, "l2") for t, c in tests]
+        from repro.core.eviction import _probe_lanes
+        lanes = _probe_lanes(tests, self.vev.prime_reps)
+        plan = ProbePlan(
+            ops=(Vote(lanes=tuple(lanes),
+                      vcpus=(self.vcpu,) * len(lanes),
+                      threshold=L2_MISS_THRESHOLD,
+                      votes=self.vev.votes),),
+            label="vcol.validate", hints=self.vev.lowering)
+        verdicts = probeplan.execute(self.vm, plan).last
         ok[np.asarray(idx, int)] = np.asarray(verdicts, bool)
         return ok
 

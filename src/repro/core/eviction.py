@@ -88,20 +88,6 @@ def validate_plan(sets: Sequence[EvictionSet], prime_reps: int,
         meta={"indices": testable, "n_sets": len(sets)})
 
 
-def _majority_verdicts(vm: GuestVM, lanes: List[np.ndarray], vcpu, thr: int,
-                       votes: int) -> np.ndarray:
-    """Fused majority-voted eviction verdicts: one batched dispatch per
-    vote, the vote index salting the per-lane rng fork so each vote is an
-    independent trial under non-deterministic replacement.  (The
-    pre-ProbePlan batched path, kept as the parity reference the executor's
-    ``Vote`` lowering is tested against.)"""
-    hits = np.zeros(len(lanes), np.int64)
-    for vote in range(votes):
-        lats = vm.timed_access_batch(lanes, vcpu=vcpu, salt=vote)
-        hits += np.array([int(l[-1] > thr) for l in lats])
-    return hits * 2 > votes
-
-
 @dataclasses.dataclass
 class EvictionSet:
     """A minimal eviction set: `gvas` all map to one cache set.
@@ -156,8 +142,7 @@ class VEV:
     """Eviction-set constructor bound to one GuestVM."""
 
     def __init__(self, vm: GuestVM, votes: int = 1, max_backtracks: int = 8,
-                 vcpu: int = 0, prime_reps: int = 1, use_batch: bool = True,
-                 use_plans: bool = True,
+                 vcpu: int = 0, prime_reps: int = 1,
                  lowering: Optional[PlanLowering] = None):
         self.vm = vm
         self.votes = votes
@@ -168,15 +153,9 @@ class VEV:
         # toward 1 (the standard technique L2FBS inherits for unknown
         # replacement policies).  1 suffices for (pseudo-)LRU.
         self.prime_reps = prime_reps
-        # use_batch routes group tests through the batched multi-set
-        # Prime+Probe engine (one fused dispatch per vote for a whole round
-        # of tests); False keeps the per-test sequential path for
-        # benchmarking the dispatch reduction.
-        self.use_batch = use_batch
-        # use_plans emits the batched tests as ProbePlan Vote programs
-        # (`probeplan.execute`); False keeps the pre-plan direct
-        # `_majority_verdicts` path as the parity reference.
-        self.use_plans = use_plans
+        # group tests run as ProbePlan Vote programs (`probeplan.execute`):
+        # one fused multi-set Prime+Probe dispatch per vote for a whole
+        # round of tests
         self.lowering = lowering
         self.stats = VEVStats()
 
@@ -217,23 +196,16 @@ class VEV:
         verdict depends only on its own in-lane accesses)."""
         if not tests:
             return np.zeros(0, bool)
-        if not self.use_batch:
-            return np.array([self.evicts(t, c, level) for t, c in tests])
         self.stats.tests += len(tests) * self.votes
-        if self.use_plans:
-            plan = vote_plan(tests, self.prime_reps, self.vcpu,
-                             self._threshold(level), self.votes,
-                             lowering=self.lowering, level=level)
-            return probeplan.execute(self.vm, plan).last
-        return _majority_verdicts(self.vm,
-                                  _probe_lanes(tests, self.prime_reps),
-                                  self.vcpu, self._threshold(level),
-                                  self.votes)
+        plan = vote_plan(tests, self.prime_reps, self.vcpu,
+                         self._threshold(level), self.votes,
+                         lowering=self.lowering, level=level)
+        return probeplan.execute(self.vm, plan).last
 
     # -- pruning ----------------------------------------------------------------
     def _prune_rounds(self, target_gva: int, cand_gvas, ways: int,
                       rng: np.random.Generator):
-        """Round generator behind :meth:`prune` in batched mode.
+        """Round generator behind :meth:`prune`.
 
         Yields one round of (target, keep-list) tests at a time and receives
         the verdict vector; a driver (``build_for_offset`` directly, or
@@ -276,35 +248,9 @@ class VEV:
         lines.  Group testing with backtracking (Vila et al.), scanning
         groups smallest-first as in L2FBS's binary-search pruning.
 
-        Batched mode drives :meth:`_prune_rounds` (one dispatch per round);
-        sequential mode keeps the seed per-test scan with early exit."""
-        if self.use_batch:
-            return _drive(self._prune_rounds(target_gva, cand_gvas, ways, rng),
-                          lambda tests: self.evicts_many(tests, level))
-        s = np.asarray(cand_gvas, np.int64)
-        backtracks = 0
-        self.stats.prunes += 1
-        while len(s) > ways:
-            n_groups = min(ways + 1, len(s))
-            perm = rng.permutation(len(s))
-            groups = np.array_split(perm, n_groups)
-            removed = False
-            for g in groups:
-                keep = np.delete(s, g)
-                if self.evicts(target_gva, keep, level):
-                    s = keep
-                    removed = True
-                    break
-            if not removed:
-                backtracks += 1
-                if backtracks > self.max_backtracks:
-                    self.stats.failures += 1
-                    return None
-        # final sanity: minimality — removing any line must break eviction.
-        if not self.evicts(target_gva, s, level):
-            self.stats.failures += 1
-            return None
-        return s
+        Drives :meth:`_prune_rounds`, one dispatch per round."""
+        return _drive(self._prune_rounds(target_gva, cand_gvas, ways, rng),
+                      lambda tests: self.evicts_many(tests, level))
 
     # -- pool construction --------------------------------------------------------
     def make_pool(self, offset: int, ways: int, n_uncontrollable_rows: int,
@@ -317,7 +263,7 @@ class VEV:
 
     def _build_rounds(self, offset: int, pool, ways: int, level: str,
                       max_sets: Optional[int], seed: int):
-        """Round generator behind :meth:`build_for_offset` in batched mode:
+        """Round generator behind :meth:`build_for_offset`:
         per target, the covered-by-built-set checks and the pool-viability
         test share one round; pruning rounds follow via
         :meth:`_prune_rounds`.  Drivers turn each round into one dispatch —
@@ -383,54 +329,9 @@ class VEV:
         """Paper §3.1 "basic steps": repeatedly pick a target from the pool;
         if no previously-built set evicts it, prune the pool remainder into a
         new minimal set and remove its lines from the pool."""
-        if self.use_batch:
-            return _drive(
-                self._build_rounds(offset, pool, ways, level, max_sets, seed),
-                lambda tests: self.evicts_many(tests, level))
-        rng = np.random.default_rng(seed)
-        pool = list(np.asarray(pool, np.int64))
-        built: List[EvictionSet] = []
-        misses = 0
-        while pool and (max_sets is None or len(built) < max_sets):
-            target = int(pool.pop(0))
-            covered = False
-            for es in built:
-                if self.evicts(target, es.gvas, level):
-                    covered = True
-                    es.add_spare(target, cap=SPARE_FACTOR * ways)
-                    break
-            if covered:
-                continue
-            if not self.evicts(target, np.array(pool, np.int64), level):
-                # pool can no longer evict this target: its set's lines are
-                # exhausted (or it needs more candidates) — skip.
-                misses += 1
-                if misses > 4 * ways:
-                    break
-                continue
-            minimal = self.prune(target, pool, ways, level, rng)
-            if minimal is None:
-                continue
-            built.append(EvictionSet(gvas=np.sort(minimal), offset=offset,
-                                     level=level))
-            self.stats.built += 1
-            taken = set(int(x) for x in minimal)
-            pool = [p for p in pool if int(p) not in taken]
-        # spare harvest (sequential twin of the batched phase above)
-        attempts = 0
-        while (pool and attempts < _SPARE_HARVEST_ROUNDS
-               and any(len(es.spares) < SPARE_FACTOR * ways
-                       for es in built)):
-            poor = [es for es in built
-                    if len(es.spares) < SPARE_FACTOR * ways]
-            targets = [int(pool.pop(0))
-                       for _ in range(min(len(pool), 96))]
-            for t in targets:
-                for es in poor:
-                    if self.evicts(t, es.gvas, level):
-                        es.add_spare(t, cap=SPARE_FACTOR * ways)
-            attempts += 1
-        return built
+        return _drive(
+            self._build_rounds(offset, pool, ways, level, max_sets, seed),
+            lambda tests: self.evicts_many(tests, level))
 
     # -- associativity probing (paper Table 3) -------------------------------------
     def probe_associativity(self, pool: np.ndarray, level: str,
@@ -459,26 +360,17 @@ class VEV:
             if len(hit):
                 s = keeps[int(hit[0])]
                 changed = True
-        # then one-at-a-time greedy removal to exact minimality; batched mode
-        # tests every single-line removal of the current set in one dispatch
-        # and drops the first line whose removal keeps the set evicting
-        if self.use_batch:
-            while len(s) > 1:
-                keeps = [np.delete(s, i) for i in range(len(s))]
-                verdicts = self.evicts_many([(target, k) for k in keeps],
-                                            level)
-                hit = np.flatnonzero(verdicts)
-                if not len(hit):
-                    break
-                s = keeps[int(hit[0])]
-        else:
-            i = 0
-            while i < len(s):
-                keep = np.delete(s, i)
-                if len(keep) and self.evicts(target, keep, level):
-                    s = keep
-                else:
-                    i += 1
+        # then one-at-a-time greedy removal to exact minimality: every
+        # single-line removal of the current set is tested in one dispatch
+        # and the first line whose removal keeps the set evicting goes
+        while len(s) > 1:
+            keeps = [np.delete(s, i) for i in range(len(s))]
+            verdicts = self.evicts_many([(target, k) for k in keeps],
+                                        level)
+            hit = np.flatnonzero(verdicts)
+            if not len(hit):
+                break
+            s = keeps[int(hit[0])]
         return len(s) if self.evicts(target, s, level) else None
 
     # -- drift validation & incremental repair (host-event recovery) -----------
@@ -499,25 +391,15 @@ class VEV:
             return np.zeros(0, bool)
         vcpus = ([self.vcpu] * len(sets) if vcpus is None else list(vcpus))
         ok = np.zeros(len(sets), bool)
-        if self.use_batch:
-            plan = validate_plan(sets, self.prime_reps, vcpus,
-                                 self._threshold(level), self.votes,
-                                 lowering=self.lowering, level=level)
-            op = plan.ops[0]
-            if op.lanes:
-                self.stats.tests += len(op.lanes) * self.votes
-                if self.use_plans:
-                    verdicts = probeplan.execute(self.vm, plan).last
-                else:
-                    verdicts = _majority_verdicts(
-                        self.vm, list(op.lanes), list(op.vcpus),
-                        op.threshold, op.votes)
-                ok[np.asarray(plan.meta["indices"], int)] = \
-                    np.asarray(verdicts, bool)
-            return ok
-        for i, es in enumerate(sets):
-            if len(es.spares):
-                ok[i] = self.evicts(int(es.spares[0]), es.gvas, level)
+        plan = validate_plan(sets, self.prime_reps, vcpus,
+                             self._threshold(level), self.votes,
+                             lowering=self.lowering, level=level)
+        op = plan.ops[0]
+        if op.lanes:
+            self.stats.tests += len(op.lanes) * self.votes
+            verdicts = probeplan.execute(self.vm, plan).last
+            ok[np.asarray(plan.meta["indices"], int)] = \
+                np.asarray(verdicts, bool)
         return ok
 
     def _verdict_round(self, tests: Sequence[Tuple[int, Sequence[int]]],
@@ -528,19 +410,13 @@ class VEV:
         if not tests:
             return np.zeros(0, bool)
         self.stats.tests += len(tests) * self.votes
-        if not self.use_batch:
-            return np.array([self.evicts(t, c, level) for t, c in tests])
         lanes = _probe_lanes(tests, self.prime_reps)
-        if self.use_plans:
-            plan = ProbePlan(
-                ops=(Vote(lanes=tuple(lanes), vcpus=tuple(lane_vcpus),
-                          threshold=self._threshold(level),
-                          votes=self.votes, level=level),),
-                label="vev.repair", hints=self.lowering)
-            return np.asarray(probeplan.execute(self.vm, plan).last, bool)
-        return np.asarray(_majority_verdicts(
-            self.vm, lanes, list(lane_vcpus), self._threshold(level),
-            self.votes), bool)
+        plan = ProbePlan(
+            ops=(Vote(lanes=tuple(lanes), vcpus=tuple(lane_vcpus),
+                      threshold=self._threshold(level),
+                      votes=self.votes, level=level),),
+            label="vev.repair", hints=self.lowering)
+        return np.asarray(probeplan.execute(self.vm, plan).last, bool)
 
     def repair_sets(self, sets: Sequence[EvictionSet], valid: np.ndarray,
                     level: str, ways: int, seed: int = 0,
@@ -648,8 +524,7 @@ class VEV:
                     for i in alias_suspect]
             results, _, _ = build_many(
                 self.vm, jobs, level, ways, votes=self.votes, seed=seed,
-                use_batch=self.use_batch, prime_reps=self.prime_reps,
-                use_plans=self.use_plans, lowering=self.lowering)
+                prime_reps=self.prime_reps, lowering=self.lowering)
             for i, built in zip(alias_suspect, results):
                 if built:
                     out[i] = built[0]
@@ -681,8 +556,7 @@ def _drive(gen, test_fn):
 
 
 def build_many(vm: GuestVM, jobs: List[Dict], level: str, ways: int,
-               votes: int = 1, seed: int = 0, use_batch: bool = True,
-               prime_reps: int = 1, use_plans: bool = True,
+               votes: int = 1, seed: int = 0, prime_reps: int = 1,
                lowering: Optional[PlanLowering] = None
                ) -> Tuple[List[List[EvictionSet]], List[int], List[int]]:
     """Merged multi-partition eviction-set construction (Fig 6).
@@ -691,11 +565,10 @@ def build_many(vm: GuestVM, jobs: List[Dict], level: str, ways: int,
     ``vcpu``.  All partitions advance in lockstep, one fused multi-set
     Prime+Probe dispatch per round across every partition still running —
     the batched realization of the paper's parallel construction (partitions
-    are disjoint rows, so their lanes never interfere).  With ``use_plans``
-    each partition's round compiles to a one-op Vote ProbePlan and the
-    round's plans are :func:`~repro.core.probeplan.fuse`\\ d into a single
-    program sharing its dispatches; ``use_plans=False`` keeps the pre-plan
-    direct `_majority_verdicts` merge (same lanes, same dispatches).
+    are disjoint rows, so their lanes never interfere).  Each partition's
+    round compiles to a one-op Vote ProbePlan and the round's plans are
+    :func:`~repro.core.probeplan.fuse`\\ d into a single program sharing
+    its dispatches.
 
     Returns (per-job built sets, per-job round counts, per-job prune-failure
     counts).  A job's round count is the number of dispatches it would have
@@ -703,20 +576,9 @@ def build_many(vm: GuestVM, jobs: List[Dict], level: str, ways: int,
     the parallel critical path.
     """
     vevs = [VEV(vm, votes=votes, vcpu=int(j.get("vcpu", 0)),
-                prime_reps=prime_reps, use_batch=use_batch,
-                use_plans=use_plans, lowering=lowering) for j in jobs]
+                prime_reps=prime_reps, lowering=lowering) for j in jobs]
     results: List[Optional[List[EvictionSet]]] = [None] * len(jobs)
     rounds: List[int] = [0] * len(jobs)
-    if not use_batch:
-        for i, (vev, j) in enumerate(zip(vevs, jobs)):
-            before = vm.stat_passes
-            results[i] = vev.build_for_offset(
-                j["offset"], j["pool"], ways, level,
-                max_sets=j.get("max_sets"), seed=seed + i)
-            rounds[i] = vm.stat_passes - before
-        return ([r or [] for r in results], rounds,
-                [v.stats.failures for v in vevs])
-
     thr = VEV._threshold(level)
     gens = {}
     pending = {}
@@ -731,26 +593,14 @@ def build_many(vm: GuestVM, jobs: List[Dict], level: str, ways: int,
         order = list(pending)
         for i in order:
             rounds[i] += votes   # dispatches this job would issue alone
-        if use_plans:
-            plans = [vote_plan(pending[i], prime_reps, vevs[i].vcpu, thr,
-                               votes, lowering=lowering, label="vev.build",
-                               level=level)
-                     for i in order]
-            fused, spans = probeplan.fuse(plans)
-            split = probeplan.split_result(probeplan.execute(vm, fused),
-                                           spans)
-            per_job = {i: r.last for i, r in zip(order, split)}
-        else:
-            lanes: List[np.ndarray] = []
-            vcpus: List[int] = []
-            bounds: Dict[int, Tuple[int, int]] = {}
-            for i in order:
-                start = len(lanes)
-                lanes.extend(_probe_lanes(pending[i], prime_reps))
-                vcpus.extend([vevs[i].vcpu] * len(pending[i]))
-                bounds[i] = (start, len(lanes))
-            verdicts = _majority_verdicts(vm, lanes, vcpus, thr, votes)
-            per_job = {i: verdicts[a:b] for i, (a, b) in bounds.items()}
+        plans = [vote_plan(pending[i], prime_reps, vevs[i].vcpu, thr,
+                           votes, lowering=lowering, label="vev.build",
+                           level=level)
+                 for i in order]
+        fused, spans = probeplan.fuse(plans)
+        split = probeplan.split_result(probeplan.execute(vm, fused),
+                                       spans)
+        per_job = {i: r.last for i, r in zip(order, split)}
         nxt = {}
         for i in order:
             vevs[i].stats.tests += len(pending[i]) * votes
@@ -778,8 +628,7 @@ class ParallelBuildResult:
 def build_parallel(vm: GuestVM, partitions: List[Dict], level: str,
                    ways: int, pair_vcpus: List[Tuple[int, int]],
                    vcpu_domain: Dict[int, int], votes: int = 1,
-                   seed: int = 0, use_batch: bool = True,
-                   use_plans: bool = True,
+                   seed: int = 0,
                    lowering: Optional[PlanLowering] = None
                    ) -> ParallelBuildResult:
     """Row-partitioned parallel construction (Fig 6).
@@ -802,8 +651,7 @@ def build_parallel(vm: GuestVM, partitions: List[Dict], level: str,
             # constructor primes in one domain, helper-assisted probes land in
             # another: every test times out; model as wasted passes + failure.
             before = vm.stat_passes
-            vev = VEV(vm, votes=votes, vcpu=ctor, use_batch=use_batch,
-                      use_plans=use_plans, lowering=lowering)
+            vev = VEV(vm, votes=votes, vcpu=ctor, lowering=lowering)
             vev.evicts(int(part["pool"][0]), part["pool"][:ways * 2], level)
             failures += 1
             per_part_passes[i] = vm.stat_passes - before
@@ -816,9 +664,7 @@ def build_parallel(vm: GuestVM, partitions: List[Dict], level: str,
         # (build_many); per-job round counts model each partition's
         # standalone cost for the Table 2 sequential-vs-critical-path report
         results, rounds, fails = build_many(vm, jobs, level, ways, votes=votes,
-                                            seed=seed, use_batch=use_batch,
-                                            use_plans=use_plans,
-                                            lowering=lowering)
+                                            seed=seed, lowering=lowering)
         for j, (built, r) in enumerate(zip(results, rounds)):
             i = job_part_idx[j]
             per_part_passes[i] = r

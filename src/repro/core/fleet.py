@@ -345,8 +345,7 @@ class FleetSim:
     def __init__(self, platform: Union[str, CachePlatform],
                  policy: str = "cas", cap: str = "on",
                  workloads: Optional[List[FleetWorkload]] = None,
-                 seed: int = 0, use_batch: bool = True,
-                 use_plans: bool = True,
+                 seed: int = 0,
                  n_intervals: int = 12, warmup: int = 4,
                  ticks_per_interval: int = 32, stream_len: int = 192,
                  ws_pages: int = 8, thresholds: Sequence[float] = (1.0, 4.0),
@@ -386,16 +385,6 @@ class FleetSim:
         self.boot_seed = seed
         self.keep_history = keep_history
         self.metrics = FleetMetrics(keep_history=keep_history)
-        self.use_batch = use_batch
-        # use_plans drives every per-interval probe through ProbePlan
-        # programs (`steps()` yields them; `run_fleet_matrix` co-executes
-        # all guests' plans in lockstep); False keeps the pre-plan
-        # per-dispatch loop as the parity/benchmark reference.  Plans are
-        # inherently batched, so the seed use_batch=False reference keeps
-        # the per-dispatch loop too (same gate as session.refresh /
-        # VScan.monitor_once).
-        self.use_plans = use_plans
-        self._plan_route = use_plans and use_batch
         self.n_intervals = n_intervals
         self.warmup = warmup
         self.ticks = ticks_per_interval
@@ -408,8 +397,7 @@ class FleetSim:
                             for v, c in enumerate(self.vm.vcpu_cores)}
 
         # -- probing stack: the same session API run_cachex drives ----------
-        cfg = ProbeConfig.for_platform(self.plat, use_batch=use_batch,
-                                       use_plans=use_plans, seed=seed,
+        cfg = ProbeConfig.for_platform(self.plat, seed=seed,
                                        prune_self_conflicts=True)
         if harvest is not None:
             # harvest scenarios monitor every core's private L2 (VSCAN
@@ -912,10 +900,7 @@ class FleetSim:
         plan's PlanResult.  Every sim on one platform yields structurally
         congruent plans in the same order, which is what lets the matrix
         driver batch all guests' per-tick probing into single vectorized
-        executions.  With ``use_plans=False`` (or the seed
-        ``use_batch=False`` reference) nothing is yielded: the loop runs
-        the pre-plan per-dispatch calls inline (parity reference).
-        Returns the :class:`FleetReport`.
+        executions.  Returns the :class:`FleetReport`.
 
         The guest's own code between two plans (view application,
         placement, CAP allocation, co-tenant retargeting) is traced as
@@ -986,13 +971,10 @@ class FleetSim:
             # domain; the published ContentionView drives the subscribed
             # CAS tiers and CAP ranking (decision stack never polls VScan)
             seq_only = k in self._seq_only_intervals
-            if self._plan_route:
-                mplan = self.session.plan()
-                if seq_only:
-                    mplan.meta["seq_only"] = True
-                view = self.session.apply(mplan, (yield mplan))
-            else:
-                view = self.session.refresh()
+            mplan = self.session.plan()
+            if seq_only:
+                mplan.meta["seq_only"] = True
+            view = self.session.apply(mplan, (yield mplan))
             dom_rates = view.per_domain
             if profiling:
                 self._attack_post(k)
@@ -1023,42 +1005,28 @@ class FleetSim:
             # *residually* — before this interval's re-traversal, so the
             # latency reflects what survived the co-tenant window in the
             # L2 — everything else keeps the after-the-stream order.
-            if self._plan_route:
-                meta = {"seq_only": True} if seq_only else {}
-                traverse = ProbePlan(
-                    ops=(Commit(segments=(
-                        Segment(gvas=self.ws_lines, vcpu=ws_vcpu),
-                        Segment(gvas=stream_lines,
-                                vcpu=self._streamer.vcpu))),),
-                    label="fleet.traverse", hints=self.lowering,
-                    meta=dict(meta))
-                ws_lat = ProbePlan(
-                    ops=(WarmTimer(),
-                         Measure(lanes=(self.ws_lines,),
-                                 vcpus=(ws_vcpu,))),
-                    label="fleet.ws_lat", hints=self.lowering,
-                    meta=dict(meta))
-                if self.harvest_mode is not None:
-                    lres = yield ws_lat
-                    lat = float(np.mean(lres.last[0]))
-                    yield traverse
-                else:
-                    yield traverse
-                    lres = yield ws_lat
-                    lat = float(np.mean(lres.last[0]))
+            meta = {"seq_only": True} if seq_only else {}
+            traverse = ProbePlan(
+                ops=(Commit(segments=(
+                    Segment(gvas=self.ws_lines, vcpu=ws_vcpu),
+                    Segment(gvas=stream_lines,
+                            vcpu=self._streamer.vcpu))),),
+                label="fleet.traverse", hints=self.lowering,
+                meta=dict(meta))
+            ws_lat = ProbePlan(
+                ops=(WarmTimer(),
+                     Measure(lanes=(self.ws_lines,),
+                             vcpus=(ws_vcpu,))),
+                label="fleet.ws_lat", hints=self.lowering,
+                meta=dict(meta))
+            if self.harvest_mode is not None:
+                lres = yield ws_lat
+                lat = float(np.mean(lres.last[0]))
+                yield traverse
             else:
-                if self.harvest_mode is not None:
-                    vm.warm_timer()
-                    lat = float(np.mean(vm.timed_access_batch(
-                        [self.ws_lines], vcpu=[ws_vcpu])[0]))
-                    vm.access(self.ws_lines, vcpu=ws_vcpu)
-                    vm.access(stream_lines, vcpu=self._streamer.vcpu)
-                else:
-                    vm.access(self.ws_lines, vcpu=ws_vcpu)
-                    vm.access(stream_lines, vcpu=self._streamer.vcpu)
-                    vm.warm_timer()
-                    lat = float(np.mean(vm.timed_access_batch(
-                        [self.ws_lines], vcpu=[ws_vcpu])[0]))
+                yield traverse
+                lres = yield ws_lat
+                lat = float(np.mean(lres.last[0]))
             if self.harvest_tier is not None:
                 # heat feed: the working set is the hot page-cache set the
                 # tier ranks promotion candidates from
@@ -1251,8 +1219,7 @@ def run_fleet_matrix(platforms: Optional[List[str]] = None,
             for sim in sims:
                 sim.tune(n_guests=len(sims))
         hints = sims[0].lowering or probeplan.DEFAULT_LOWERING
-        if (lockstep and len(sims) > 1 and hints.lockstep
-                and all(s.use_plans and s.use_batch for s in sims)):
+        if lockstep and len(sims) > 1 and hints.lockstep:
             reports.extend(_run_lockstep(sims))
         else:
             reports.extend(sim.run() for sim in sims)

@@ -131,8 +131,7 @@ class CacheXReport:
 # queries and, since the ProbePlan redesign, to plan()/execute().)
 
 def run_cachex(platform: Union[str, CachePlatform],
-               seed: Optional[int] = None,
-               use_batch: Optional[bool] = None, monitor_intervals: int = 3,
+               seed: Optional[int] = None, monitor_intervals: int = 3,
                config: Optional[ProbeConfig] = None,
                host_vm: Optional[Tuple[SimHost, GuestVM]] = None,
                tune: bool = False) -> CacheXReport:
@@ -141,25 +140,20 @@ def run_cachex(platform: Union[str, CachePlatform],
     All probing routes through one :class:`CacheXSession`; this function
     only sequences the experiment (quiesce / burst phases) and builds the
     hypercall-validated report.  ``config`` overrides the platform-default
-    :class:`ProbeConfig`; explicitly passed ``seed``/``use_batch``
-    arguments take precedence over it (left unset they default to the
-    config's values, i.e. seed 0 / batched).  ``host_vm`` reuses an
-    already-booted pair instead of booting a fresh scenario: the host is
-    left clean (the measurement burst this driver attaches is removed
-    again, co-tenant enabled states are restored) and the report's cost
-    counters are deltas for this run only.  ``tune=True`` replaces the
-    platform's hinted plan lowering with the autotuner's choice for the
-    session's monitoring plan before any monitoring runs
-    (``CacheXSession.tuned_lowering``; model-only — no cutout timing)."""
+    :class:`ProbeConfig`; an explicitly passed ``seed`` takes precedence
+    over it (left unset it defaults to the config's, i.e. seed 0).
+    ``host_vm`` reuses an already-booted pair instead of booting a fresh
+    scenario: the host is left clean (the measurement burst this driver
+    attaches is removed again, co-tenant enabled states are restored) and
+    the report's cost counters are deltas for this run only.
+    ``tune=True`` replaces the platform's hinted plan lowering with the
+    autotuner's choice for the session's monitoring plan before any
+    monitoring runs (``CacheXSession.tuned_lowering``; model-only — no
+    cutout timing)."""
     plat = get_platform(platform) if isinstance(platform, str) else platform
     cfg = config if config is not None else ProbeConfig.for_platform(plat)
-    overrides = {}
     if seed is not None:
-        overrides["seed"] = seed
-    if use_batch is not None:
-        overrides["use_batch"] = use_batch
-    if overrides:
-        cfg = cfg.replace(**overrides)
+        cfg = cfg.replace(seed=seed)
     host, vm = (host_vm if host_vm is not None
                 else plat.make_host_vm(seed=cfg.seed))
     passes0, accesses0 = vm.stat_passes, vm.stat_accesses
@@ -237,9 +231,9 @@ def run_cachex(platform: Union[str, CachePlatform],
     )
 
 
-def run_matrix(platforms: Optional[List[str]] = None, seed: int = 0,
-               use_batch: bool = True) -> List[CacheXReport]:
+def run_matrix(platforms: Optional[List[str]] = None,
+               seed: int = 0) -> List[CacheXReport]:
     """run_cachex across the whole registry (or a named subset)."""
     from repro.core.platforms import list_platforms
     names = platforms if platforms is not None else list_platforms()
-    return [run_cachex(n, seed=seed, use_batch=use_batch) for n in names]
+    return [run_cachex(n, seed=seed) for n in names]

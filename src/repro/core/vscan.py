@@ -125,7 +125,6 @@ class VScan:
     def __init__(self, vm: GuestVM, monitored: List[MonitoredSet],
                  window_ms: float = DEFAULT_WINDOW_MS,
                  ewma_alpha: float = 0.3, n_pairs: int = 1,
-                 use_batch: bool = True, use_plans: bool = True,
                  lowering: Optional[PlanLowering] = None,
                  drift_frac: float = DRIFT_FRAC,
                  drift_intervals: int = DRIFT_INTERVALS):
@@ -150,16 +149,10 @@ class VScan:
         # — legitimate heavy contention keeps suspicion streaks alive, and
         # the cooldown bounds the zero-wait re-checks it can trigger
         self._confirm_cooldown = 0
-        # use_batch probes every monitored set as one lane of a single fused
-        # multi-set Prime+Probe dispatch (Table 6); False keeps the seed
-        # one-dispatch-per-set probe loop for benchmarking.
-        self.use_batch = use_batch
-        # use_plans compiles each interval to a ProbePlan (fused multi-vCPU
-        # prime Commit + Wait + timed probe Measure) executed by
+        # each interval compiles to a ProbePlan (fused multi-vCPU prime
+        # Commit + Wait + timed probe Measure) executed by
         # `probeplan.execute` — the route `monitor_plan()`/`apply_monitor()`
-        # expose so a fleet harness can co-execute many guests' intervals;
-        # False keeps the pre-plan per-prober prime loop (parity reference).
-        self.use_plans = use_plans
+        # expose so a fleet harness can co-execute many guests' intervals
         self.lowering = lowering
         self.ewma = np.zeros(len(monitored))
         # the latest snapshots only: a session monitors for its lifetime
@@ -174,8 +167,7 @@ class VScan:
               votes: int = 1, seed: int = 0,
               window_ms: float = DEFAULT_WINDOW_MS,
               ewma_alpha: float = 0.3,
-              use_batch: bool = True,
-              prime_reps: int = 1, use_plans: bool = True,
+              prime_reps: int = 1,
               lowering: Optional[PlanLowering] = None
               ) -> Tuple["VScan", Dict]:
         """Split pool into color groups, partition by offset, build f sets
@@ -202,9 +194,8 @@ class VScan:
         # all (domain, color, offset) partitions advance in lockstep sharing
         # fused dispatches (Fig 6 parallel construction)
         results, _, _ = build_many(vm, jobs, "llc", ways, votes=votes,
-                                   seed=seed, use_batch=use_batch,
-                                   prime_reps=prime_reps,
-                                   use_plans=use_plans, lowering=lowering)
+                                   seed=seed, prime_reps=prime_reps,
+                                   lowering=lowering)
         for (domain, vcpu, color), sets in zip(job_meta, results):
             if not sets:
                 info["failed_partitions"] += 1
@@ -213,8 +204,7 @@ class VScan:
                     es=es, color=color, domain=domain, vcpu=vcpu))
                 info["built"] += 1
         return cls(vm, monitored, window_ms=window_ms,
-                   ewma_alpha=ewma_alpha, use_batch=use_batch,
-                   use_plans=use_plans, lowering=lowering), info
+                   ewma_alpha=ewma_alpha, lowering=lowering), info
 
     # -- persistence (the `CacheXSession` export contract) ---------------------
     def state_dict(self) -> Dict:
@@ -235,7 +225,6 @@ class VScan:
 
     @classmethod
     def from_state(cls, vm: GuestVM, state: Dict,
-                   use_batch: bool = True, use_plans: bool = True,
                    lowering: Optional[PlanLowering] = None) -> "VScan":
         monitored = [MonitoredSet(es=EvictionSet.from_state(m["es"]),
                                   color=int(m["color"]),
@@ -244,8 +233,7 @@ class VScan:
                                   level=str(m.get("level", "llc")))
                      for m in state["monitored"]]
         vs = cls(vm, monitored, window_ms=float(state["default_window_ms"]),
-                 ewma_alpha=float(state["ewma_alpha"]), use_batch=use_batch,
-                 use_plans=use_plans, lowering=lowering)
+                 ewma_alpha=float(state["ewma_alpha"]), lowering=lowering)
         vs.window_ms = float(state["window_ms"])
         return vs
 
@@ -260,36 +248,6 @@ class VScan:
         for i, m in enumerate(self.monitored):
             by_prober.setdefault(m.vcpu, []).append(i)
         return by_prober
-
-    def _prime(self, by_prober: Dict[int, List[int]]) -> None:
-        """Each thread pair traverses its share with MLP batching."""
-        for vcpu, idxs in by_prober.items():
-            lines = np.concatenate([self.monitored[i].es.gvas for i in idxs])
-            self.vm.access(lines, vcpu=vcpu)
-
-    def _probe(self, by_prober: Dict[int, List[int]]) -> np.ndarray:
-        """Per-set evicted-line fraction (reverse-order timed probe)."""
-        frac = np.zeros(len(self.monitored))
-        if self.use_batch and self.monitored:
-            # one fused dispatch probes every monitored set (its own lane,
-            # reverse order, issued from its prober's core)
-            order = [i for idxs in by_prober.values() for i in idxs]
-            lanes = [self.monitored[i].es.gvas[::-1] for i in order]
-            vcpus = [self.monitored[i].vcpu for i in order]
-            self.vm.warm_timer()
-            lat_lanes = self.vm.timed_access_batch(lanes, vcpu=vcpus)
-            for i, lats in zip(order, lat_lanes):
-                thr = miss_threshold(self.monitored[i].level)
-                frac[i] = float(np.mean(lats > thr))
-        else:
-            for vcpu, idxs in by_prober.items():
-                for i in idxs:
-                    gvas = self.monitored[i].es.gvas[::-1]  # reverse order
-                    self.vm.warm_timer()
-                    lats = self.vm.timed_access(gvas, vcpu=vcpu)
-                    thr = miss_threshold(self.monitored[i].level)
-                    frac[i] = float(np.mean(lats > thr))
-        return frac
 
     # -- plan emission (the ProbePlan route) -----------------------------------
     def _interval_ops(self, by_prober: Dict[int, List[int]],
@@ -389,15 +347,11 @@ class VScan:
         if not self.monitored:
             return 0
         by_prober = self._by_prober()
-        if self.use_batch and self.use_plans:
-            ops, order = self._interval_ops(by_prober, window_ms=None)
-            plan = ProbePlan(ops=ops, label="vscan.prune",
-                             hints=self.lowering)
-            frac = self._frac_from_lanes(
-                order, probeplan.execute(self.vm, plan).last)
-        else:
-            self._prime(by_prober)
-            frac = self._probe(by_prober)
+        ops, order = self._interval_ops(by_prober, window_ms=None)
+        plan = ProbePlan(ops=ops, label="vscan.prune",
+                         hints=self.lowering)
+        frac = self._frac_from_lanes(
+            order, probeplan.execute(self.vm, plan).last)
         keep = frac <= max_frac
         dropped = int((~keep).sum())
         if dropped:
@@ -418,13 +372,10 @@ class VScan:
         nothing between the prime Commit and the timed Measure.  Any
         eviction it sees is self-inflicted, i.e. structural."""
         by_prober = self._by_prober()
-        if self.use_batch and self.use_plans:
-            ops, order = self._interval_ops(by_prober, window_ms=None)
-            plan = ProbePlan(ops=ops, label=label, hints=self.lowering)
-            return self._frac_from_lanes(
-                order, probeplan.execute(self.vm, plan).last)
-        self._prime(by_prober)
-        return self._probe(by_prober)
+        ops, order = self._interval_ops(by_prober, window_ms=None)
+        plan = ProbePlan(ops=ops, label=label, hints=self.lowering)
+        return self._frac_from_lanes(
+            order, probeplan.execute(self.vm, plan).last)
 
     def drift_suspects(self) -> np.ndarray:
         """Indices of live monitored sets whose anomaly streak reached
@@ -518,20 +469,11 @@ class VScan:
         self.ewma[index] = 0.0
 
     def monitor_once(self) -> VScanSnapshot:
-        """Prime -> wait(window) -> probe (reverse order, timed).  One
-        ProbePlan execution on the default route (2 dispatches: fused
-        multi-vCPU prime + fused probe); the pre-plan per-prober prime
-        loop survives behind ``use_plans=False`` as the parity reference,
-        and ``use_batch=False`` keeps the seed one-dispatch-per-set
-        probe."""
-        if self.use_batch and self.use_plans:
-            plan = self.monitor_plan()
-            return self.apply_monitor(plan, probeplan.execute(self.vm, plan))
-        by_prober = self._by_prober()
-        self._prime(by_prober)
-        self.vm.wait_ms(self.window_ms)
-        frac = self._probe(by_prober)
-        return self._finish_interval(frac, self.window_ms)
+        """Prime -> wait(window) -> probe (reverse order, timed): one
+        ProbePlan execution (2 dispatches: fused multi-vCPU prime + fused
+        probe)."""
+        plan = self.monitor_plan()
+        return self.apply_monitor(plan, probeplan.execute(self.vm, plan))
 
     # -- aggregation (consumed by CAS / CAP) -------------------------------------
     # Quarantined (flagged) sets are excluded: their EWMA is frozen drift

@@ -51,7 +51,7 @@ def test_probe_config_platform_defaults_and_overrides():
     shared = ProbeConfig.for_platform("skylake_shared")
     assert shared.votes == get_platform("skylake_shared").votes == 3
     cfg = ProbeConfig.for_platform("skylake_sp")
-    assert (cfg.votes, cfg.prime_reps, cfg.use_batch) == (1, 1, True)
+    assert (cfg.votes, cfg.prime_reps) == (1, 1)
     over = ProbeConfig.for_platform("skylake_sp", votes=5, f=4)
     assert over.votes == 5 and over.f == 4
     assert over.replace(seed=9).seed == 9
@@ -192,6 +192,19 @@ def test_import_rejects_foreign_payload():
         CacheXSession.import_(vm, {"format": "something-else"})
 
 
+def test_import_skips_retired_config_knobs():
+    """Snapshots written while ProbeConfig still had the `use_batch` /
+    `use_plans` route switches import: the retired keys are dropped and
+    every live knob survives."""
+    plat = get_platform(FAST_PLATFORM)
+    host, vm = plat.make_host_vm(seed=2)
+    cfg = ProbeConfig.for_platform(plat, seed=2, f=3)
+    data = CacheXSession.attach(vm, plat, cfg).export()
+    data["config"].update(use_batch=False, use_plans=False)
+    session = CacheXSession.import_(vm.reboot(seed=3), data)
+    assert session.config == cfg
+
+
 def test_reboot_preserves_backing_and_reserve_pages():
     plat = get_platform(FAST_PLATFORM)
     host, vm = plat.make_host_vm(seed=5)
@@ -287,8 +300,8 @@ def test_run_cachex_removes_measurement_burst():
 
 
 def test_run_cachex_explicit_config_is_respected():
-    """An explicitly passed ProbeConfig is authoritative; seed/use_batch
-    arguments override it only when actually given."""
+    """An explicitly passed ProbeConfig is authoritative; a seed argument
+    overrides it only when actually given."""
     plat = get_platform(FAST_PLATFORM)
     cfg = ProbeConfig.for_platform(plat, seed=7, vev_target_sets=2)
     r = run_cachex(plat, monitor_intervals=1, config=cfg)

@@ -248,14 +248,26 @@ def test_vscan_quarantine_is_no_longer_one_way():
     assert vs.flagged.all(), "broken sets must stay quarantined"
 
 
+@pytest.fixture(scope="module")
+def healthy_victim():
+    """One victim attached on a healthy cache, shared by the property's
+    examples: an attach takes seconds, an example must not."""
+    return _attach_victim(FAST_PLATFORM, 7)
+
+
 @settings(max_examples=8)
 @given(idxs=st.lists(st.integers(0, 7), min_size=1, max_size=8),
        attack=st.booleans())
-def test_confirm_clean_lifts_any_intact_quarantine(idxs, attack):
+def test_confirm_clean_lifts_any_intact_quarantine(healthy_victim, idxs,
+                                                   attack):
     """Property form: whatever subset is quarantined on a healthy cache,
     one `confirm_clean()` lifts all of it and resets suspicion."""
-    plat, host, vm, session = _attach_victim(FAST_PLATFORM, 7)
+    plat, host, vm, session = healthy_victim
     vs = session._vs
+    # every example starts with nothing quarantined or suspected
+    vs.flagged[:] = False
+    vs.attack_flagged[:] = False
+    vs._suspect[:] = 0
     idxs = sorted({i % len(vs.monitored) for i in idxs})
     vs.flag_sets(idxs, attack=attack)
     assert set(vs.confirm_clean()) == set(idxs)

@@ -13,6 +13,7 @@ from repro.core.fleet import (FleetReport, FleetSim, default_workloads,
                               fig10_summary, fleet_interval_progress,
                               run_fleet, speedup_summary)
 from repro.core.platforms import get_platform
+from repro.core.probeplan import ProbePlan
 
 
 # ---------------------------------------------------------------------------
@@ -119,12 +120,17 @@ def test_fleet_report_row_contract(fleet_pair):
 
 
 def test_fleet_view_widens_topology():
-    sim_plat = FleetSim("icelake_sp", n_intervals=0).plat
+    sim = FleetSim("icelake_sp", n_intervals=1)
+    sim_plat = sim.plat
     base = get_platform("icelake_sp")
     assert sim_plat.n_domains >= 2
     assert (sim_plat.cores_per_domain
             >= max(base.cores_per_domain, len(default_workloads())))
     assert sim_plat.llc == base.llc and sim_plat.provisioning == base.provisioning
+    # the closed loop's probe points are ProbePlans (what the lockstep
+    # driver co-executes), the interval's monitoring plan first
+    plan = sim.steps().send(None)
+    assert isinstance(plan, ProbePlan) and plan.label == "vscan.monitor"
 
 
 # ---------------------------------------------------------------------------

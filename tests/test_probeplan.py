@@ -3,7 +3,7 @@
 Covers:
   * executor unit semantics — Commit segment fusion (one dispatch,
     state-identical to per-segment traversals), Measure lane trimming,
-    Vote majority verdicts vs the pre-plan `_majority_verdicts` reference,
+    Vote majority verdicts vs the hypercall congruence oracle,
     Wait/WarmTimer side effects;
   * plan fusion — `fuse` merges structurally congruent plans into one
     program sharing dispatches and `split_result` restores per-plan
@@ -13,14 +13,18 @@ Covers:
     machine states vs single-guest execution, congruence/shared-host
     guards;
   * plan-vs-legacy parity, property-style: the whole VEV/VCOL/VSCAN
-    pipeline (`run_cachex`) with `use_plans=True` must reproduce the
-    pre-redesign path's report field for field on every platform (tier-1:
+    pipeline (`run_cachex`) must reproduce, field for field, the reports
+    recorded from the pre-redesign path on every platform (tier-1:
     skylake_sp; rest `slow`), and the closed-loop fleet must reproduce its
-    reports across legacy / plan / lockstep execution while the lockstep
-    matrix issues >= 2x fewer physical probe dispatches per tick.
+    recorded reports under sequential and lockstep plan execution while
+    the lockstep matrix issues >= 2x fewer physical probe dispatches per
+    tick than the recorded legacy loop.  The recordings live in
+    ``tests/data``; each says how it was made under ``"provenance"``.
 """
 
 import dataclasses
+import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +34,7 @@ import pytest
 from repro.core import cachesim, probeplan, trace
 from repro.core.abstraction import ProbeConfig
 from repro.core.cachesim import CacheGeometry, MachineGeometry
-from repro.core.eviction import VEV, _majority_verdicts, _probe_lanes
+from repro.core.eviction import VEV, _probe_lanes
 from repro.core.host_model import (CotenantWorkload, GuestVM, SimHost,
                                    polluter_gen, probe_dispatch_count)
 from repro.core.plancost import SHAPE_CACHE
@@ -41,6 +45,20 @@ from repro.core.runner import run_cachex
 from tests.conftest import make_vm
 
 FAST_PLATFORM = "skylake_sp"
+DATA = os.path.join(os.path.dirname(__file__), "data")
+
+
+def _fixture(name):
+    with open(os.path.join(DATA, name)) as f:
+        return json.load(f)
+
+
+def _as_recorded(report, skip):
+    """A report's fields as the fixtures hold them: JSON-encoded (dict
+    keys become strings, numpy scalars plain numbers)."""
+    return {f.name: json.loads(json.dumps(getattr(report, f.name),
+                                          default=lambda o: o.item()))
+            for f in dataclasses.fields(report) if f.name not in skip}
 
 
 def _matrix_params():
@@ -115,12 +133,10 @@ def test_measure_returns_trimmed_per_lane_latencies():
 
 
 def test_vote_matches_pre_plan_majority_verdicts():
-    """The executor's Vote lowering must reach exactly the verdicts of the
-    pre-plan `_majority_verdicts` reference on identical tests (LRU:
-    measurement lanes are uncommitted, so back-to-back runs see the same
-    snapshot)."""
+    """The executor's Vote lowering must reach exactly the hypercall
+    oracle's verdicts: a test evicts iff it holds at least ``ways`` lines
+    congruent with its target (`hypercall_llc_setslice`)."""
     host, vm = make_vm(seed=15)
-    vev = VEV(vm, use_plans=False)
     pages = vm.alloc_pages(256)
     target = vm.gva(int(pages[0]), 0)
     key = vm.hypercall_llc_setslice(target)
@@ -132,7 +148,9 @@ def test_vote_matches_pre_plan_majority_verdicts():
     tests = [(target, np.array(cong[:ways + 2])),
              (target, np.array(other[:2 * ways]))]
     thr = VEV._threshold("llc")
-    ref = _majority_verdicts(vm, _probe_lanes(tests, 1), 0, thr, votes=3)
+    ref = np.array([
+        sum(vm.hypercall_llc_setslice(int(g)) == key for g in c) >= ways
+        for _, c in tests])
     plan = ProbePlan(ops=(Vote(lanes=tuple(_probe_lanes(tests, 1)),
                                vcpus=(0, 0), threshold=thr, votes=3),))
     got = probeplan.execute(vm, plan).last
@@ -240,16 +258,6 @@ def test_execute_many_guards():
     salted = ProbePlan(ops=(Measure(lanes=lane, vcpus=(0,), salt=3),))
     with pytest.raises(ValueError):          # rng salts must agree
         probeplan.execute_many([vm1, vm2], [measure, salted])
-
-
-def test_fleet_seed_unbatched_reference_keeps_per_dispatch_route():
-    """`use_batch=False` is the seed per-dispatch benchmark reference:
-    plans are inherently batched, so the fleet loop must fall back to the
-    pre-plan route exactly like session.refresh / VScan.monitor_once do."""
-    from repro.core.fleet import FleetSim
-    assert not FleetSim(FAST_PLATFORM, n_intervals=0,
-                        use_batch=False)._plan_route
-    assert FleetSim(FAST_PLATFORM, n_intervals=0)._plan_route
 
 
 def _distinct_states(geom, g):
@@ -374,20 +382,19 @@ def test_lockstep_wait_compiles_once_per_shard_and_bucket():
 @pytest.mark.parametrize("name", _matrix_params())
 def test_pipeline_plan_vs_legacy_parity(name):
     """VEV + VCOL + VSCAN + CAS/CAP through `run_cachex`: the ProbePlan
-    route must reproduce the pre-redesign path's report field for field
-    (everything except dispatch/wall cost — fused commits are the point)."""
+    route must reproduce the pre-redesign path's recorded report field for
+    field (everything except dispatch/wall cost — fused commits are the
+    point), and issue no more dispatches than that path did."""
     plat = get_platform(name)
-    reports = {}
-    for use_plans in (True, False):
-        cfg = ProbeConfig.for_platform(plat, seed=3, use_plans=use_plans)
-        reports[use_plans] = run_cachex(plat, monitor_intervals=2,
-                                        config=cfg)
-    a, b = reports[True], reports[False]
-    for f in dataclasses.fields(type(a)):
-        if f.name in ("dispatches", "wall_s"):
-            continue
-        assert getattr(a, f.name) == getattr(b, f.name), f.name
-    assert a.dispatches <= b.dispatches      # fusion never adds dispatches
+    cfg = ProbeConfig.for_platform(plat, seed=3)
+    a = run_cachex(plat, monitor_intervals=2, config=cfg)
+    want = _fixture("pipeline_reports.json")["reports"][name]
+    got = _as_recorded(a, ("dispatches", "wall_s"))
+    assert got.keys() == want.keys() - {"pre_plan_dispatches"}
+    for field, value in got.items():
+        assert value == want[field], field
+    # fusion never adds dispatches
+    assert a.dispatches <= want["pre_plan_dispatches"]
 
 
 def _cotenant_counts(since=(0, 0)):
@@ -399,18 +406,17 @@ def _cotenant_counts(since=(0, 0)):
 def test_fleet_lockstep_parity_and_dispatch_reduction():
     """The fleet acceptance property: lockstep multi-guest execution
     reproduces every report metric bit for bit vs both the sequential plan
-    path and the pre-plan legacy path, while issuing >= 2x fewer physical
-    probe dispatches per tick than the legacy per-guest loop, and G times
-    fewer co-tenant engine calls than the sequential plan path."""
+    path and the recorded pre-plan legacy path, while issuing >= 2x fewer
+    physical probe dispatches per tick than the legacy per-guest loop did
+    (recorded), and G times fewer co-tenant engine calls than the
+    sequential plan path."""
     from repro.core.fleet import FleetSim, _run_lockstep
     combos = (("eevdf", "on"), ("cas", "on"), ("cas", "off"))
     kw = dict(n_intervals=6, warmup=2, seed=0)
 
-    legacy_sims = [FleetSim(FAST_PLATFORM, policy=p, cap=c,
-                            use_plans=False, **kw) for p, c in combos]
-    d0 = probe_dispatch_count()
-    legacy = [s.run() for s in legacy_sims]
-    legacy_loop = probe_dispatch_count() - d0
+    recorded = _fixture("fleet_lockstep_reports.json")
+    legacy = [recorded["reports"][f"{p}-{c}"] for p, c in combos]
+    legacy_loop = recorded["legacy_loop_dispatches"]
 
     seq_sims = [FleetSim(FAST_PLATFORM, policy=p, cap=c, **kw)
                 for p, c in combos]
@@ -427,10 +433,12 @@ def test_fleet_lockstep_parity_and_dispatch_reduction():
 
     skip = ("dispatches", "wall_s", "guests_per_sec")
     for l, s, k in zip(legacy, seq, lock):
-        for f in dataclasses.fields(type(l)):
+        got = _as_recorded(s, skip)
+        assert got.keys() == l.keys()
+        for f in dataclasses.fields(type(s)):
             if f.name in skip:
                 continue
-            assert getattr(l, f.name) == getattr(s, f.name), f.name
+            assert got[f.name] == l[f.name], f.name
             assert getattr(s, f.name) == getattr(k, f.name), f.name
     # the acceptance ratio: physical probe dispatches per tick, whole fleet
     assert legacy_loop >= 2 * lock_loop, (legacy_loop, lock_loop)

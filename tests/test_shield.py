@@ -159,14 +159,15 @@ def test_population_resize_resets_scores():
 
 
 def test_labeled_fixture_differential():
-    """Traces recorded from the real simulator (see module docstring of
-    the generator in the fixture) must replay through `classify_trace`
+    """Traces recorded from the real simulator (the fixture's
+    ``provenance`` says how) must replay through `classify_trace`
     to exactly the recorded verdicts — any classifier change that moves
     these labels is a behavior change, not a refactor."""
     with open(FIXTURE) as f:
         fx = json.load(f)
-    assert set(fx) == {"benign", "attack"}
-    for name, rec in fx.items():
+    assert set(fx) == {"provenance", "benign", "attack"}
+    for name in ("benign", "attack"):
+        rec = fx[name]
         out = classify_trace([np.array(w, float) for w in rec["fracs"]])
         assert out == rec["expected"], name
     assert fx["benign"]["expected"]["detected"] is False
